@@ -82,8 +82,7 @@ def _pallas_steady_state(seed: int, verbose: bool) -> dict:
     base_wall = []
     for _ in range(PALLAS_BASELINE_CALLS):
         t0 = time.perf_counter()
-        out = np.asarray(cgra_exec(exe.lowered, flats[:8], n_iters,
-                                   interpret=True))
+        out = np.asarray(cgra_exec(exe.lowered, flats[:8], n_iters))
         base_wall.append(time.perf_counter() - t0)
     baseline_s = sum(base_wall) / len(base_wall)
     bitexact = all(np.array_equal(out[b], oracle[b]) for b in range(8))

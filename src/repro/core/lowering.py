@@ -34,8 +34,9 @@ from typing import Tuple
 
 import numpy as np
 
-from repro.core.machine import (MachineConfig, SRC_CONST, SRC_NONE, SRC_REG,
-                                SRC_SELF, XB_IN, XB_NONE, XB_O, XB_REG)
+from repro.core.machine import (OPC, MachineConfig, SRC_CONST, SRC_NONE,
+                                SRC_REG, SRC_SELF, XB_IN, XB_NONE, XB_O,
+                                XB_REG)
 
 K_NONE, K_O, K_R, K_CONST, K_RESULT = 0, 1, 2, 3, 4
 
@@ -91,6 +92,104 @@ class LinkedConfig:
 
     def total_cycles(self, n_iters: int) -> int:
         return self.t0_max + n_iters * self.II + self.II + 2
+
+
+# Field layout of the Pallas kernel's tables (``kernel_tables``).
+# Per-PE vector table, one (P, KV_FIELDS) row block per slot:
+KV_OPC, KV_CONST, KV_T0OK, KV_LIVE, KV_Q0 = 0, 1, 2, 3, 4
+#: operand k occupies fields KV_OP + 5k .. KV_OP + 5k + 4:
+#: [gather source, is-immediate, dist, init, takes-trailing-immediate]
+KV_OP = 5
+KV_FIELDS = KV_OP + 15
+# Register-write table, one (P*R, KR_FIELDS) row block per slot:
+#: [move source, result source PE, its live flag, its q0]
+KR_MOVE, KR_RES, KR_RES_LIVE, KR_RES_Q0 = 0, 1, 2, 3
+KR_FIELDS = 4
+# Scalar (SMEM) table, KS_FIELDS words per (slot, memory PE):
+KS_OPC, KS_CONST, KS_LIVE, KS_Q0, KS_HAS_IDX, KS_HAS2 = 0, 1, 2, 3, 4, 5
+KS_FIELDS = 6
+
+
+def kernel_tables(linked: LinkedConfig
+                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The dense tables re-laid out for the Pallas kernel: ``(stab, vtab,
+    rtab)``.
+
+    The kernel keeps every table 2-D per slot with a wide minor axis (the
+    TPU's (8, 128) tiling refuses the raw tables' minor dims of 3 and 5),
+    reads memory-PE fields as scalars, and does no vector integer
+    division.  So everything static is folded here, once:
+
+      * ``vtab`` (S, P, KV_FIELDS): per-PE columns.  Operand sources index
+        the stacked ``[O; R]`` state (``pe`` for K_O, ``P + pe*R + reg``
+        for K_R, -1 otherwise).  ``q0`` makes the iteration index a
+        subtraction: with ``t = q*II + s``, ``(t - t0) // II == q - q0``.
+      * ``rtab`` (S, P*R, KR_FIELDS): per-register-row writes, with the
+        source PE's ``live``/``q0`` copied in so ``fired[src]`` needs no
+        gather.
+      * ``stab`` (S * n_mem * KS_FIELDS,) int32: the memory PEs' fields,
+        flat for scalar memory.
+
+    Memoized on the instance (underscore attribute: never pickled).
+    """
+    cached = getattr(linked, "_kernel_tables", None)
+    if cached is not None:
+        return cached
+    S, P, R = linked.II, linked.n_pes, linked.n_regs
+    sc, ops, rw = linked.scalar, linked.ops, linked.regw
+    opc, const, use_c, t0 = (sc[..., i] for i in range(4))
+    t0ok = t0 >= 0
+    live = (opc != OPC["NOP"]) & t0ok
+    slot = np.arange(S)[:, None]
+    t0c = np.where(t0ok, t0, 0)
+    q0 = np.where(t0ok, t0c // S + (slot < t0c % S), 0)
+
+    def src(kind, pe, reg):
+        return np.where(kind == K_O, pe,
+                        np.where(kind == K_R, P + pe * R + reg, -1))
+
+    vtab = np.zeros((S, P, KV_FIELDS), np.int32)
+    vtab[..., KV_OPC] = opc
+    vtab[..., KV_CONST] = const
+    vtab[..., KV_T0OK] = t0ok
+    vtab[..., KV_LIVE] = live
+    vtab[..., KV_Q0] = q0
+    kinds = ops[..., 0]
+    n_ops = (kinds != K_NONE).sum(axis=-1)
+    for k in range(3):
+        kind, pe, reg, dist, init = (ops[:, :, k, i] for i in range(5))
+        b = KV_OP + 5 * k
+        vtab[..., b] = src(kind, pe, reg)
+        vtab[..., b + 1] = kind == K_CONST
+        vtab[..., b + 2] = dist
+        vtab[..., b + 3] = init
+        # the immediate is a *trailing* ALU operand when use_const is set
+        vtab[..., b + 4] = (kind == K_NONE) & (use_c != 0) & (n_ops == k)
+
+    rk, rp, rr = rw[..., 0], rw[..., 1], rw[..., 2]          # (S, P, R)
+    is_res = rk == K_RESULT
+    rtab = np.zeros((S, P, R, KR_FIELDS), np.int32)
+    rtab[..., KR_MOVE] = src(rk, rp, rr)
+    rtab[..., KR_RES] = np.where(is_res, rp, -1)
+    rtab[..., KR_RES_LIVE] = is_res & np.take_along_axis(live, rp.reshape(
+        S, -1), axis=1).reshape(S, P, R)
+    rtab[..., KR_RES_Q0] = np.take_along_axis(q0, rp.reshape(S, -1),
+                                              axis=1).reshape(S, P, R)
+    rtab = rtab.reshape(S, P * R, KR_FIELDS)
+
+    mp = list(linked.mem_pes)
+    stab = np.zeros((S, len(mp), KS_FIELDS), np.int32)
+    if mp:
+        stab[..., KS_OPC] = opc[:, mp]
+        stab[..., KS_CONST] = const[:, mp]
+        stab[..., KS_LIVE] = live[:, mp]
+        stab[..., KS_Q0] = q0[:, mp]
+        stab[..., KS_HAS_IDX] = ops[:, mp, 0, 0] != K_NONE
+        stab[..., KS_HAS2] = ops[:, mp, 1, 0] != K_NONE
+    # scalar memory cannot hold an empty array
+    stab = stab.reshape(-1) if stab.size else np.zeros(1, np.int32)
+    linked._kernel_tables = (stab, vtab, rtab)
+    return linked._kernel_tables
 
 
 def lowered_fingerprint(linked: LinkedConfig) -> str:

@@ -8,56 +8,107 @@ so a 1:1 port would waste the TPU.  Instead:
     instances) is vectorized across VPU lanes — each lane is one CGRA
     instance, the per-cycle PE update is a (P, lanes) elementwise block;
   * the configuration memory (the paper's CM, 52% of CGRA power because
-    it is read every cycle) is the linked table image, resident in VMEM
-    for the whole kernel — the "CM stays on-chip" analogue;
+    it is read every cycle) is the linked table image, resident on-chip
+    for the whole kernel — the "CM stays on-chip" analogue: per-PE
+    columns in VMEM, the memory PEs' fields as scalars in SMEM;
   * HyCUBE's single-cycle multi-hop routes were resolved at link time
-    (kernels/cgra_exec/linking.py), so operand fetch is a static one-hot
-    gather over the PE state — compiler-scheduled routing with zero
-    dynamic-routing hardware, exactly the paper's bet;
+    (``core.lowering``), so operand fetch is a static select chain over
+    the stacked ``[O; R]`` PE state — compiler-scheduled routing with
+    zero dynamic-routing hardware, exactly the paper's bet;
   * the scratchpad lives in VMEM as an (M, lanes) block; LOAD/STORE are
-    data-dependent per lane and become one-hot compare/select reductions
-    (TPU has no per-lane gather; this is the idiomatic replacement).
+    data-dependent per lane and become compare/select passes over it
+    (TPU has no per-lane gather; this is the idiomatic replacement), run
+    only in cycles where the memory PE actually loads or stores.
 
-Grid: (batch_tiles,) — each grid step simulates ``total_cycles`` of the
-whole fabric for one batch tile via ``fori_loop`` carrying (O, R, mem).
+Grid: (batch_tiles,) — each grid step simulates the whole fabric for one
+batch tile.  Cycle ``t = q*II + s``: nested ``fori_loop``s over the round
+``q`` and the slot ``s`` carry (O, R); the slot indexes the tables' leading
+axis, so no integer division runs per cycle.  Running up to ``II - 1`` cycles past
+``LinkedConfig.total_cycles`` changes nothing: no PE fires there, and
+only firing stores write the scratchpad.
 
-``n_iters`` is a *traced* scalar (a ``(1, 1)`` int32 operand, read inside
-the kernel): the cycle count becomes a dynamic ``fori_loop`` bound and
-per-PE firing is masked on the traced iteration count, so ONE trace of the
-kernel serves every iteration count — the property the persistent JIT
-engine (``repro.ual.engine``) builds its trace-once/run-many cache on.
+``n_iters`` is a *traced* scalar (a ``(1, 1)`` int32 operand in SMEM):
+the cycle count is a dynamic ``fori_loop`` bound and per-PE firing is
+masked on the traced iteration count, so ONE trace of the kernel serves
+every iteration count — the property the persistent JIT engine
+(``repro.ual.engine``) builds its trace-once/run-many cache on.
 ``make_cgra_call`` is the shared constructor of the ``pallas_call``; both
 the one-shot ``cgra_exec`` wrapper and the engine go through it.
+
+Whether the kernel is compiled (Mosaic) or interpreted is decided by the
+platform, in ``interpret_mode``: compiled on a TPU, interpreted elsewhere.
 """
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.lowering import (K_CONST, K_NONE, K_O, K_R, K_RESULT,
-                                 LinkedConfig)
+from repro.core.lowering import (KR_MOVE, KR_RES, KR_RES_LIVE, KR_RES_Q0,
+                                 KS_CONST, KS_FIELDS, KS_HAS2, KS_HAS_IDX,
+                                 KS_LIVE, KS_OPC, KS_Q0, KV_CONST, KV_LIVE,
+                                 KV_OP, KV_OPC, KV_Q0, KV_T0OK, LinkedConfig,
+                                 kernel_tables)
 from repro.core.machine import OPC
 
 I32 = jnp.int32
 
+#: rows of the scratchpad one compare/select pass touches at a time
+_MEM_CHUNK = 512
 
-def _sel_rows(idx, table):
-    """table[idx] for idx (P,) int32 over table (N, B) — one-hot gather.
 
-    TPU-friendly: avoids dynamic per-row gathers; (P, N) one-hot times
-    (N, B) state collapses to compare/multiply/sum on the VPU.
+def interpret_mode() -> bool:
+    """The one place the execution mode is chosen: the Mosaic-compiled
+    kernel on a TPU, the Pallas interpreter on every other platform."""
+    return jax.default_backend() != "tpu"
+
+
+#: the persistent compile cache's fixed home inside the checkout
+#: (src/repro/kernels/cgra_exec/kernel.py -> <repo>/artifacts/jax_cache);
+#: a fixed path, because the path is part of every cache entry's key
+JAX_CACHE_DIR = Path(__file__).resolve().parents[4] / "artifacts" / "jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets no other directory; otherwise the cache goes to
+    ``JAX_CACHE_DIR``.  A kernel compiles in about a second, around JAX's
+    default one-second threshold for keeping an entry, so the threshold
+    is lowered to keep every kernel.
     """
-    N = table.shape[0]
-    oh = (idx[:, None] == jax.lax.broadcasted_iota(I32, (1, N), 1)).astype(I32)
-    return jnp.sum(oh[:, :, None] * table[None, :, :], axis=1)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(JAX_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    return jax.config.jax_compilation_cache_dir
 
 
-def _alu(opc, v0, v1, v2, const, use_const_mask):
+def _gather(st_ref, idx, lo: int, n: int):
+    """Rows ``idx`` (X, 1) of ``st_ref[lo:lo + n]`` -> (X, B); an index
+    outside ``[0, n)`` (-1: no source) selects 0.  One select per source
+    row, in a loop: the TPU has no per-row vector gather, and an unrolled
+    chain would keep every broadcast row live at once."""
+    def body(r, out):
+        return jnp.where(idx == r, st_ref[pl.ds(lo + r, 1), :], out)
+    return jax.lax.fori_loop(
+        0, n, body, jnp.zeros((idx.shape[0], st_ref.shape[1]), I32))
+
+
+def _lanes(flag, B: int):
+    """A scalar flag (SMEM word or scalar predicate) as a (1, B) mask."""
+    return jnp.full((1, B), flag.astype(I32)) != 0
+
+
+def _alu(opc, v0, v1, v2, cvec):
     """Vectorized ALU: all opcodes computed, selected by ``opc`` (P, 1)."""
     sh5 = jnp.bitwise_and(v1, 31)
+
     def cmp(c):
         return c.astype(I32)
     cases = {
@@ -66,12 +117,12 @@ def _alu(opc, v0, v1, v2, const, use_const_mask):
         "SHR": jax.lax.shift_right_arithmetic(v0, sh5),
         "AND": v0 & v1, "OR": v0 | v1, "XOR": v0 ^ v1,
         "MIN": jnp.minimum(v0, v1), "MAX": jnp.maximum(v0, v1),
-        "ABS": jnp.abs(v0),
+        "ABS": jnp.where(v0 < 0, -v0, v0),
         "CMPLT": cmp(v0 < v1), "CMPGT": cmp(v0 > v1),
         "CMPEQ": cmp(v0 == v1), "CMPNE": cmp(v0 != v1),
         "CMPLE": cmp(v0 <= v1), "CMPGE": cmp(v0 >= v1),
         "SELECT": jnp.where(v0 != 0, v1, v2),
-        "MOVC": jnp.broadcast_to(const, v0.shape),
+        "MOVC": cvec,
         "ROUTE": v0,
     }
     out = jnp.zeros_like(v0)
@@ -80,132 +131,198 @@ def _alu(opc, v0, v1, v2, const, use_const_mask):
     return out
 
 
-def _cgra_kernel(niter_ref, scalar_ref, ops_ref, regw_ref, mem_in_ref,
-                 mem_out_ref, *, II: int, n_pes: int, n_regs: int, mem_pes,
-                 t_max: int):
+def _mem_passes(mem_ref, chunk: int):
+    """``(load, store)`` over the (M, B) scratchpad ref, one ``chunk``-row
+    block at a time so a pass costs one block of vector registers."""
+    M, B = mem_ref.shape
+    n_chunks = M // chunk
+
+    def rows(c):
+        start = pl.multiple_of(c * chunk, chunk)
+        return pl.ds(start, chunk), \
+            jax.lax.broadcasted_iota(I32, (chunk, 1), 0) + start
+
+    def load(addr):                              # addr (1, B) -> (1, B)
+        def body(c, acc):
+            sl, idx = rows(c)
+            return jnp.where(idx == addr, mem_ref[sl, :], acc)
+        acc = jax.lax.fori_loop(0, n_chunks, body,
+                                jnp.zeros((chunk, B), I32))
+        return jnp.sum(acc, axis=0, keepdims=True)
+
+    def store(addr, val):                        # (1, B) each
+        def body(c, carry):
+            sl, idx = rows(c)
+            mem_ref[sl, :] = jnp.where(idx == addr, val, mem_ref[sl, :])
+            return carry
+        jax.lax.fori_loop(0, n_chunks, body, 0)
+
+    return load, store
+
+
+def _cgra_kernel(niter_ref, stab_ref, vtab_ref, rtab_ref, mem_in_ref,
+                 mem_out_ref, st_ref, *, II: int, n_pes: int, n_regs: int,
+                 mem_pes, t_max: int, chunk: int):
     P, R = n_pes, n_regs
+    N = P + P * R             # st_ref rows: [O; R] state, then the results
+    M, B = mem_out_ref.shape
     n_iters = niter_ref[0, 0]           # traced: one trace, any trip count
-    total_cycles = t_max + (n_iters + 1) * II + 2
-    scalar = scalar_ref[...]            # (S, P, 4)
-    optab = ops_ref[...]                # (S, P, 3, 5)
-    rwtab = regw_ref[...]               # (S, P, R, 3)
-    mem0 = mem_in_ref[...]              # (M, B)
-    M, B = mem0.shape
+    # ceil(total_cycles / II) rounds of the II slots
+    n_rounds = n_iters + 1 + (t_max + 2 + II - 1) // II
+    load, store = _mem_passes(mem_out_ref, chunk)
 
-    def cycle(t, carry):
-        out_latch, Rf, mem = carry      # (P,B), (P*R,B), (M,B)
-        s = t % II
-        sc = jax.lax.dynamic_index_in_dim(scalar, s, 0, keepdims=False)
-        op = jax.lax.dynamic_index_in_dim(optab, s, 0, keepdims=False)
-        rw = jax.lax.dynamic_index_in_dim(rwtab, s, 0, keepdims=False)
-        opc, const, use_c, t0 = sc[:, 0], sc[:, 1], sc[:, 2], sc[:, 3]
-        it = jnp.where(t0 >= 0, (t - t0) // II, 0)            # (P,)
-        fired = (opc != OPC["NOP"]) & (t0 >= 0) & (t >= t0) & (it < n_iters)
-        cvec = jnp.broadcast_to(const[:, None], (P, B))
+    def copy(c, carry):
+        sl = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        mem_out_ref[sl, :] = mem_in_ref[sl, :]
+        return carry
+    jax.lax.fori_loop(0, M // chunk, copy, 0)
 
-        # ---- operand fetch: static gathers over previous-cycle state -----
+    def fires(live, q0, q):
+        it = q - q0
+        return (live != 0) & (it >= 0) & (it < n_iters), it
+
+    def cycle(q, s):
+        O, Rf = st_ref[0:P, :], st_ref[P:N, :]
+        tab = vtab_ref[s]                                   # (P, F)
+
+        def col(f):
+            return tab[:, f:f + 1]                          # (P, 1)
+        opc = col(KV_OPC)
+        fired, it = fires(col(KV_LIVE), col(KV_Q0), q)
+        it = jnp.where(col(KV_T0OK) != 0, it, 0)
+        cvec = jnp.broadcast_to(col(KV_CONST), (P, B))
+
+        # ---- operand fetch: selects over the previous-cycle state ---------
         def operand(k):
-            kind, pe, reg = op[:, k, 0], op[:, k, 1], op[:, k, 2]
-            dist, init = op[:, k, 3], op[:, k, 4]
-            v = jnp.where((kind == K_O)[:, None],
-                          _sel_rows(pe, out_latch), 0)
-            v = jnp.where((kind == K_R)[:, None],
-                          _sel_rows(pe * R + reg, Rf), v)
-            v = jnp.where((kind == K_CONST)[:, None], cvec, v)
-            use_init = (dist > 0) & (it < dist)
-            v = jnp.where(use_init[:, None],
-                          jnp.broadcast_to(init[:, None], (P, B)), v)
-            return kind, v
+            b = KV_OP + 5 * k
+            v = _gather(st_ref, col(b), 0, N)
+            v = jnp.where(col(b + 1) != 0, cvec, v)
+            dist = col(b + 2)
+            v = jnp.where((dist > 0) & (it < dist),
+                          jnp.broadcast_to(col(b + 3), (P, B)), v)
+            return jnp.where(col(b + 4) != 0, cvec, v)
 
-        k0, v0 = operand(0)
-        k1, v1 = operand(1)
-        k2, v2 = operand(2)
-        # the immediate is a *trailing* ALU operand when use_const is set
-        n_ops = ((k0 != K_NONE).astype(I32) + (k1 != K_NONE).astype(I32)
-                 + (k2 != K_NONE).astype(I32))
-        uc = use_c != 0
-        v0 = jnp.where(((k0 == K_NONE) & uc & (n_ops == 0))[:, None], cvec, v0)
-        v1 = jnp.where(((k1 == K_NONE) & uc & (n_ops == 1))[:, None], cvec, v1)
-        v2 = jnp.where(((k2 == K_NONE) & uc & (n_ops == 2))[:, None], cvec, v2)
-
-        result = _alu(opc[:, None], v0, v1, v2, const[:, None], uc)
+        v0, v1, v2 = operand(0), operand(1), operand(2)
+        result = _alu(opc, v0, v1, v2, cvec)
 
         # ---- memory ops: sequential over LSU-capable PEs (port order) ----
-        iota_m = jax.lax.broadcasted_iota(I32, (M, 1), 0)
-        for mp in mem_pes:
-            is_ld = fired[mp] & (opc[mp] == OPC["LOAD"])
-            is_st = fired[mp] & (opc[mp] == OPC["STORE"])
-            has_idx = op[mp, 0, 0] != K_NONE
-            l_addr = jnp.where(has_idx, v0[mp], 0) + const[mp]        # (B,)
-            lval = jnp.sum(jnp.where(iota_m == l_addr[None, :], mem, 0),
-                           axis=0)
-            has2 = op[mp, 1, 0] != K_NONE
-            s_addr = jnp.where(has2, v0[mp] + const[mp], const[mp])
-            s_val = jnp.where(has2, v1[mp], v0[mp])
-            addr = jnp.where(is_st, s_addr, l_addr)
-            mem = jnp.where(is_st & (iota_m == addr[None, :]),
-                            s_val[None, :], mem)
-            row = jnp.where(is_ld, lval, jnp.where(is_st, s_val, result[mp]))
-            result = jnp.where(
-                (jax.lax.broadcasted_iota(I32, (P, 1), 0) == mp), row[None, :],
-                result)
+        pe_row = jax.lax.broadcasted_iota(I32, (P, 1), 0)
+        for j, mp in enumerate(mem_pes):
+            base = (s * len(mem_pes) + j) * KS_FIELDS
+            m_opc = stab_ref[base + KS_OPC]
+            m_const = stab_ref[base + KS_CONST]
+            m_fired, _ = fires(stab_ref[base + KS_LIVE],
+                               stab_ref[base + KS_Q0], q)
+            is_ld = m_fired & (m_opc == OPC["LOAD"])
+            is_st = m_fired & (m_opc == OPC["STORE"])
+            a0 = v0[mp:mp + 1, :]
+            a1 = v1[mp:mp + 1, :]
+            has_idx = _lanes(stab_ref[base + KS_HAS_IDX], B)
+            has2 = _lanes(stab_ref[base + KS_HAS2], B)
+            l_addr = jnp.where(has_idx, a0, 0) + m_const
+            lval = jax.lax.cond(is_ld, load, jnp.zeros_like, l_addr)
+            s_addr = jnp.where(has2, a0 + m_const, m_const)
+            s_val = jnp.where(has2, a1, a0)
+
+            @pl.when(is_st)
+            def _():
+                store(s_addr, s_val)
+
+            row = jnp.where(_lanes(is_ld, B), lval,
+                            jnp.where(_lanes(is_st, B), s_val,
+                                      result[mp:mp + 1, :]))
+            result = jnp.where(pe_row == mp, row, result)
 
         # ---- end of cycle: register writes, then output latches -----------
-        rwk = rw[:, :, 0].reshape(P * R)
-        rwp = rw[:, :, 1].reshape(P * R)
-        rwr = rw[:, :, 2].reshape(P * R)
-        from_o = _sel_rows(rwp, out_latch)
-        from_r = _sel_rows(rwp * R + rwr, Rf)
-        from_res = _sel_rows(rwp, result)
-        fired_src = _sel_rows(rwp, fired.astype(I32)[:, None]
-                              * jnp.ones((P, B), I32))
-        Rf_new = jnp.where((rwk == K_O)[:, None], from_o, Rf)
-        Rf_new = jnp.where((rwk == K_R)[:, None], from_r, Rf_new)
-        Rf_new = jnp.where(((rwk == K_RESULT)[:, None]) & (fired_src != 0),
-                           from_res, Rf_new)
-        O_new = jnp.where(fired[:, None], result, out_latch)
-        return O_new, Rf_new, mem
+        rt = rtab_ref[s]                                    # (P*R, F)
+        st_ref[N:N + P, :] = result
+        moved = _gather(st_ref, rt[:, KR_MOVE:KR_MOVE + 1], 0, N)
+        Rf_new = jnp.where(rt[:, KR_MOVE:KR_MOVE + 1] >= 0, moved, Rf)
+        res_fired, _ = fires(rt[:, KR_RES_LIVE:KR_RES_LIVE + 1],
+                             rt[:, KR_RES_Q0:KR_RES_Q0 + 1], q)
+        from_res = _gather(st_ref, rt[:, KR_RES:KR_RES + 1], N, P)
+        Rf_new = jnp.where(res_fired, from_res, Rf_new)
+        st_ref[0:P, :] = jnp.where(fired, result, O)
+        st_ref[P:N, :] = Rf_new
 
-    O0 = jnp.zeros((P, B), I32)
-    R0 = jnp.zeros((P * R, B), I32)
-    _, _, mem = jax.lax.fori_loop(0, total_cycles, cycle, (O0, R0, mem0))
-    mem_out_ref[...] = mem
+    def round_(q, carry):
+        def slot(s, c):
+            cycle(q, s)
+            return c
+        return jax.lax.fori_loop(0, II, slot, carry)
+
+    st_ref[...] = jnp.zeros(st_ref.shape, I32)
+    jax.lax.fori_loop(0, n_rounds, round_, 0)
+
+
+def _mem_chunk(M: int) -> int:
+    """Largest power-of-two row block (8 .. ``_MEM_CHUNK``) dividing ``M``;
+    ``M`` itself when none does."""
+    c = _MEM_CHUNK
+    while c >= 8:
+        if M % c == 0:
+            return c
+        c //= 2
+    return M
+
+
+def _vmem_limit_bytes(M: int, bB: int) -> int:
+    """Scoped-VMEM budget for one grid step: the in and out scratchpad
+    blocks, double-buffered by the pipeline (lanes pad to 128), plus
+    headroom for the tables, the loop state and the compiler's scratch.
+    At M = 8192 and 128 lanes that is 4 x 4 MiB + 16 MiB = 32 MiB — over
+    v5e's 16 MiB default scoped limit, well inside its 128 MiB of VMEM."""
+    block = M * max(128, -(-bB // 128) * 128) * 4
+    return 4 * block + (16 << 20)
 
 
 def make_cgra_call(linked: LinkedConfig, *, M: int, bB: int,
-                   n_tiles: int = 1, interpret: bool = False):
+                   n_tiles: int = 1):
     """Build the ``pallas_call`` executing ``linked`` over ``n_tiles``
     batch tiles of ``bB`` lanes each.
 
-    Returns a callable ``(niter, scalar, ops, regw, memT) -> memT'`` where
+    Returns a callable ``(niter, stab, vtab, rtab, memT) -> memT'`` where
     ``niter`` is a (1, 1) int32 array (the traced trip count), the tables
-    are the dense linked images and ``memT`` is the (M, n_tiles * bB)
-    transposed scratchpad block.  Everything *shape-like* (tile geometry,
-    table dims, the schedule's ``t0_max``) is static; the trip count is
-    not — one trace serves every ``n_iters``.
+    are ``core.lowering.kernel_tables(linked)`` and ``memT`` is the
+    (M, n_tiles * bB) transposed scratchpad block.  Everything
+    *shape-like* (tile geometry, table dims, the schedule's ``t0_max``) is
+    static; the trip count is not — one trace serves every ``n_iters``.
+
+    The platform decides whether the kernel is compiled or interpreted
+    (``interpret_mode``); on a TPU the persistent compile cache is placed
+    before the first kernel compiles (``use_compile_cache``).
     """
+    interpret = interpret_mode()
+    if not interpret:
+        use_compile_cache()
     kernel = functools.partial(
         _cgra_kernel, II=linked.II, n_pes=linked.n_pes,
-        n_regs=linked.n_regs, mem_pes=linked.mem_pes, t_max=linked.t0_max)
-    S, P, R = linked.II, linked.n_pes, linked.n_regs
+        n_regs=linked.n_regs, mem_pes=linked.mem_pes, t_max=linked.t0_max,
+        chunk=_mem_chunk(M))
+    _, vtab, rtab = kernel_tables(linked)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((S, P, 4), lambda i: (0, 0, 0)),
-            pl.BlockSpec((S, P, 3, 5), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((S, P, R, 3), lambda i: (0, 0, 0, 0)),
+            smem,
+            smem,
+            pl.BlockSpec(vtab.shape, lambda i: (0, 0, 0)),
+            pl.BlockSpec(rtab.shape, lambda i: (0, 0, 0)),
             pl.BlockSpec((M, bB), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((M, bB), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((M, n_tiles * bB), I32),
+        scratch_shapes=[pltpu.VMEM(
+            (linked.n_pes * (linked.n_regs + 2), bB), I32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(M, bB)),
         interpret=interpret,
     )
 
 
 def cgra_exec(linked: LinkedConfig, mem: jax.Array, n_iters, *,
-              lanes: int = 128, interpret: bool = False) -> jax.Array:
+              lanes: int = 128) -> jax.Array:
     """Execute ``linked`` for ``n_iters`` iterations over mem (B, M) int32.
 
     Returns the final scratchpad images, (B, M) int32.  One-shot wrapper:
@@ -216,9 +333,8 @@ def cgra_exec(linked: LinkedConfig, mem: jax.Array, n_iters, *,
     bB = min(lanes, max(8, B))
     pad = (-B) % bB
     memT = jnp.pad(mem, ((0, pad), (0, 0))).T.astype(I32)     # (M, B')
-    call = make_cgra_call(linked, M=M, bB=bB, n_tiles=(B + pad) // bB,
-                          interpret=interpret)
-    out = call(jnp.asarray(n_iters, I32).reshape(1, 1),
-               jnp.asarray(linked.scalar), jnp.asarray(linked.ops),
-               jnp.asarray(linked.regw), memT)
+    call = make_cgra_call(linked, M=M, bB=bB, n_tiles=(B + pad) // bB)
+    tables = [jnp.asarray(t) for t in kernel_tables(linked)]
+    out = call(jnp.asarray(n_iters, I32).reshape(1, 1), *tables, memT)
     return out.T[:B]
+

@@ -31,12 +31,11 @@ def _memoized_link(cfg: MachineConfig) -> LinkedConfig:
 
 
 def cgra_exec_op(cfg: MachineConfig, mem: np.ndarray, n_iters: int, *,
-                 lanes: int = 128, interpret: bool = True,
+                 lanes: int = 128,
                  linked: Optional[LinkedConfig] = None) -> np.ndarray:
     """Execute a mapped CGRA configuration over a batch of test vectors.
 
-    mem: (B, M) int32 scratchpad images.  interpret=True on CPU (the TPU
-    lowering is exercised by the dry-run harness, not here).  ``linked``
+    mem: (B, M) int32 scratchpad images.  ``linked``
     supplies a precomputed lowered artifact (e.g. the one memoized by the
     ``ual`` compile pipeline); when omitted the config is lowered through
     a per-process fingerprint memo, so no caller lowers the same
@@ -48,5 +47,5 @@ def cgra_exec_op(cfg: MachineConfig, mem: np.ndarray, n_iters: int, *,
         linked = _memoized_link(cfg)
     from repro.ual.engine import default_engine
     out, _ = default_engine().run(linked, np.asarray(mem, np.int32), n_iters,
-                                  lanes=lanes, interpret=interpret)
+                                  lanes=lanes)
     return out

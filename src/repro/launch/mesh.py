@@ -62,13 +62,16 @@ def forced_device_env(n: int,
                       base: Optional[Mapping[str, str]] = None) -> dict:
     """Environment dict for a *subprocess* that should see ``n`` host
     devices: a copy of ``base`` (default ``os.environ``) with the forced
-    count patched into ``XLA_FLAGS``.  The escape hatch when jax is
-    already live in the current process — the child reads the flag at
-    its own backend init."""
+    count patched into ``XLA_FLAGS`` and ``JAX_PLATFORMS=cpu``.  The
+    escape hatch when jax is already live in the current process — the
+    child reads the flag at its own backend init.  The child is held to
+    the CPU: the forced devices are host devices, and on a TPU host the
+    parent may hold the chip, which a second process cannot open."""
     if n < 1:
         raise ValueError(f"forced device count must be >= 1, got {n}")
     env = dict(base if base is not None else os.environ)
     env["XLA_FLAGS"] = _with_forced_count(env.get("XLA_FLAGS", ""), n)
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

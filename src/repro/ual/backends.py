@@ -8,7 +8,8 @@ arrays.  Three ship with the repo:
   * ``sim``     — the vectorized, natively-batched simulator executing
     the lowered configuration tables (``core.simulator.simulate_batch``),
   * ``pallas``  — the Pallas ``cgra_exec`` TPU kernel executing the same
-    tables (batched; interpret-mode on CPU) through the persistent JIT
+    tables (batched; compiled on a TPU, interpreted on any other
+    platform) through the persistent JIT
     engine (``repro.ual.engine``): trace-once/run-many with batch-bucket
     padding, tables device-resident per engine, ``n_iters`` traced.
 
@@ -163,7 +164,7 @@ class SimBackend(Backend):
 
 
 class PallasBackend(Backend):
-    """Pallas ``cgra_exec`` TPU kernel (interpret-mode on CPU), executed
+    """Pallas ``cgra_exec`` TPU kernel (interpreted off-TPU), executed
     through the persistent JIT engine (``repro.ual.engine``): the linked
     tables live on device per engine, ``n_iters`` is traced, and batch
     sizes are padded up the bucket ladder so repeat traffic hits warm
@@ -184,10 +185,9 @@ class PallasBackend(Backend):
     consumes_lowered = True
     accepts_flats = True
 
-    def __init__(self, lanes: int = 128, interpret: bool = True,
-                 engine=None, sharded: bool = False):
+    def __init__(self, lanes: int = 128, engine=None,
+                 sharded: bool = False):
         self.lanes = lanes
-        self.interpret = interpret
         self._engine = engine        # None -> the process-wide engine cache
         self.sharded = sharded
         # a sharded sweep spans every device; pinning it to one is a
@@ -211,10 +211,8 @@ class PallasBackend(Backend):
         """The (cached) engine executing ``linked`` under this backend's
         opts — sharded or single-device, per the registration."""
         if self.sharded:
-            return self.engine.sharded_engine_for(linked, lanes=self.lanes,
-                                                  interpret=self.interpret)
+            return self.engine.sharded_engine_for(linked, lanes=self.lanes)
         return self.engine.engine_for(linked, lanes=self.lanes,
-                                      interpret=self.interpret,
                                       device=device)
 
     def execute_batch(self, program, result, mems, n_iters, lowered=None,
@@ -224,13 +222,10 @@ class PallasBackend(Backend):
         linked = _ensure_lowered(result, lowered)
         if self.sharded:
             out, info = self.engine.sharded_run(linked, flats, n_iters,
-                                                lanes=self.lanes,
-                                                interpret=self.interpret)
+                                                lanes=self.lanes)
         else:
             out, info = self.engine.run(linked, flats, n_iters,
-                                        lanes=self.lanes,
-                                        interpret=self.interpret,
-                                        device=device)
+                                        lanes=self.lanes, device=device)
         info["batched"] = True
         return program.unflatten_batch(out), info
 

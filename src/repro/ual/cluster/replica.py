@@ -34,7 +34,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 
 class ReplicaSlot:
@@ -72,6 +72,18 @@ class ReplicaSlot:
         }
 
 
+def default_devices(slots: int) -> List:
+    """Where ``slots`` replicas go when no devices are given: on a TPU,
+    slot ``i`` on ``jax.devices()[i]`` — one chip per replica, in this
+    one process, since a chip belongs to one process; elsewhere every
+    slot on the default device (``None``)."""
+    from repro.kernels.cgra_exec.kernel import interpret_mode
+    if interpret_mode():
+        return [None] * slots
+    import jax
+    return jax.devices()[:slots]
+
+
 class Router:
     """Least-loaded dispatch + idle work stealing over N replica slots."""
 
@@ -79,11 +91,10 @@ class Router:
                  ) -> None:
         if slots < 1:
             raise ValueError(f"need at least 1 replica slot, got {slots}")
-        devs = list(devices) if devices else [None] * slots
-        if devices and len(devs) < slots:
+        devs = list(devices) if devices else default_devices(slots)
+        if len(devs) < slots:
             raise ValueError(f"{slots} slots but only {len(devs)} devices")
-        self.slots = [ReplicaSlot(i, devs[i] if devices else None)
-                      for i in range(slots)]
+        self.slots = [ReplicaSlot(i, devs[i]) for i in range(slots)]
         self._cond = threading.Condition()
         self._stopped = False
         self.decisions: Dict[str, int] = {"affinity": 0, "least_loaded": 0}

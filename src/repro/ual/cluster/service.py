@@ -50,10 +50,12 @@ parent's.
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import itertools
 import multiprocessing as mp
 import os
 import queue as queue_mod
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -203,6 +205,31 @@ class _Flight:
     n_iters: int
     deadline: Optional[float]         # absolute parent perf_counter
     retries: int = 0
+
+
+def _tpu_platform() -> bool:
+    """Whether JAX in this process would run on a TPU, decided without
+    starting JAX: a front end that stays off JAX leaves the chip free.
+    ``JAX_PLATFORMS`` (or the live config) decides when set; otherwise
+    JAX takes the TPU whenever its runtime (``libtpu``) is installed."""
+    jax = sys.modules.get("jax")
+    plats = (jax.config.jax_platforms if jax is not None
+             else os.environ.get("JAX_PLATFORMS"))
+    if plats:
+        return plats.split(",")[0].strip() == "tpu"
+    return importlib.util.find_spec("libtpu") is not None
+
+
+def _check_backend_off_chip(backend: str) -> None:
+    """Refuse a device backend on a TPU: each spawned worker would open
+    the chip, and a chip belongs to one process at a time."""
+    from repro.ual.backends import PallasBackend, get_backend
+    if isinstance(get_backend(backend), PallasBackend) and _tpu_platform():
+        raise ValueError(
+            f"ClusterService cannot run backend {backend!r} on a TPU: its "
+            f"worker processes cannot share the chip.  Serve device "
+            f"backends from one process with ual.Service(replicas=K), "
+            f"which puts replica i on jax.devices()[i].")
 
 
 class ClusterService:
@@ -417,6 +444,7 @@ class ClusterService:
         arrays = dict(mem or {})
         arrays.update(named)
         program.check_arrays(arrays)
+        _check_backend_off_chip(target.backend)
         n = n_iters if n_iters is not None else program.n_iters
         class_id = (program.digest, target.digest, target.backend, n)
         resp = Response()

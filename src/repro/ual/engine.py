@@ -9,9 +9,11 @@ is the opposite: produce the compiled artifact ONCE, execute it many
 times.  This module is that half of the story:
 
   * ``CompiledKernelCache`` — the engine registry, keyed on
-    ``(lowered fingerprint, backend opts)`` with per-``(M, bucket)`` trace
-    entries below that: the full key of one compiled trace is
-    ``(lowering fingerprint, backend opts, batch bucket)``,
+    ``(lowered fingerprint, lanes, placement)`` with per-``(M, bucket)``
+    trace entries below that: the full key of one compiled trace is
+    ``(lowered fingerprint, lanes, placement, batch bucket)``.  The kernel
+    runs compiled on a TPU and interpreted elsewhere — the platform
+    decides (``kernels.cgra_exec.kernel.interpret_mode``), no caller does,
   * each ``KernelEngine`` wraps the shared ``cgra_exec`` kernel body in
     ONE ``jax.jit`` with the linked tables uploaded to device once and
     closed over as constants (the CM-in-VMEM analogue at the host level),
@@ -54,7 +56,7 @@ Multi-device (the serving-cluster substrate, ``repro.ual.cluster``):
     local devices.  Padding is per-device — a global block is
     ``n_devices x bucket_for(ceil(chunk / n_devices))`` rows — so the
     bucket-ladder trace economy survives sharding unchanged.  Engines
-    are cached per ``(fingerprint, lanes, interpret, placement)`` via
+    are cached per ``(fingerprint, lanes, placement)`` via
     ``engine_for(device=...)`` / ``sharded_engine_for``.
 """
 from __future__ import annotations
@@ -68,7 +70,8 @@ from typing import (Dict, Iterable, Iterator, List, Optional, Sequence,
 import numpy as np
 
 from repro import obs
-from repro.core.lowering import LinkedConfig, lowered_fingerprint
+from repro.core.lowering import (LinkedConfig, kernel_tables,
+                                 lowered_fingerprint)
 
 
 def make_cgra_call(*args, **kwargs):
@@ -112,15 +115,18 @@ class KernelEngine:
         return {}
 
     def __init__(self, linked: LinkedConfig, *, lanes: int = 128,
-                 interpret: bool = True,
                  buckets: Optional[Sequence[int]] = None,
                  device=None) -> None:
         import jax
         import jax.numpy as jnp
 
+        from repro.kernels.cgra_exec.kernel import interpret_mode
+
         self.linked = linked
         self.lanes = lanes
-        self.interpret = interpret
+        #: what actually runs: the Pallas interpreter, or the
+        #: Mosaic-compiled kernel on a TPU
+        self.mode = "interp" if interpret_mode() else "tpu"
         self.device = device          # None -> jax default placement
         self.buckets = bucket_ladder(lanes, buckets)
         self.fingerprint = lowered_fingerprint(linked)
@@ -149,11 +155,11 @@ class KernelEngine:
 
     # -- placement (overridden by the sharded engine) -------------------------
     def _put_tables(self, linked: LinkedConfig) -> tuple:
-        """Upload the CM image to this engine's placement."""
+        """Upload the kernel's CM image to this engine's placement."""
         jax, jnp = self._jax, self._jnp
         return tuple(
             jax.device_put(jnp.asarray(t, jnp.int32), self.device)
-            for t in (linked.scalar, linked.ops, linked.regw))
+            for t in kernel_tables(linked))
 
     def _put_operand(self, arr):
         """One per-call operand (niter / mem block) onto the placement.
@@ -168,8 +174,7 @@ class KernelEngine:
         """``mem`` is one padded (bucket, M) block; retraced per shape."""
         self.traces += 1
         bucket, M = mem.shape
-        call = make_cgra_call(self.linked, M=M, bB=bucket, n_tiles=1,
-                              interpret=self.interpret)
+        call = make_cgra_call(self.linked, M=M, bB=bucket, n_tiles=1)
         return call(niter, *self._tables, mem.T).T
 
     # -- execution ------------------------------------------------------------
@@ -445,6 +450,10 @@ class KernelEngine:
             "bucket_calls": bucket_calls,
             "hit_ratio": round(hits / calls, 4) if calls else None,
             "buckets": self.buckets,
+            "mode": self.mode,
+            # where the tables (and so every sweep) live
+            "platform": ",".join(sorted(
+                {d.platform for d in self._tables[0].devices()})),
             **snap,
             **self._info_extra(),
         }
@@ -462,8 +471,10 @@ class ShardedKernelEngine(KernelEngine):
     bucket-ladder trace economy is unchanged — the warm-shape set and
     trace count stay O(#buckets) while throughput scales with the mesh.
 
-    ``check_rep=False`` on the shard_map is required: pallas_call has no
-    replication rule, and the body touches only per-device data anyway.
+    The replicated tables enter the ``jax.shard_map`` as operands with
+    ``P()`` specs (closing over arrays placed on an Explicit mesh is not
+    implemented), and ``check_vma=False``: pallas_call has no
+    varying-manual-axes rule, and the body touches only per-device data.
 
     Parity contract: bit-exact with the single-device engine (and the
     interp oracle) for every batch size, including ragged final chunks —
@@ -474,7 +485,6 @@ class ShardedKernelEngine(KernelEngine):
     ENGINE_NAME = "pallas-jit-sharded"
 
     def __init__(self, linked: LinkedConfig, *, lanes: int = 128,
-                 interpret: bool = True,
                  buckets: Optional[Sequence[int]] = None,
                  mesh=None) -> None:
         if mesh is None:
@@ -487,8 +497,7 @@ class ShardedKernelEngine(KernelEngine):
         self.mesh = mesh
         self.axis = mesh.axis_names[0]
         self.n_devices = int(mesh.devices.size)
-        super().__init__(linked, lanes=lanes, interpret=interpret,
-                         buckets=buckets)
+        super().__init__(linked, lanes=lanes, buckets=buckets)
 
     def _info_extra(self) -> Dict[str, object]:
         return {"n_devices": self.n_devices}
@@ -500,7 +509,7 @@ class ShardedKernelEngine(KernelEngine):
         rep = NamedSharding(self.mesh, PartitionSpec())
         return tuple(
             jax.device_put(jnp.asarray(t, jnp.int32), rep)
-            for t in (linked.scalar, linked.ops, linked.regw))
+            for t in kernel_tables(linked))
 
     def _put_operand(self, arr):
         return self._jnp.asarray(arr)
@@ -509,21 +518,22 @@ class ShardedKernelEngine(KernelEngine):
         """``mem`` is one (n_devices * bucket, M) global block; each
         device's shard runs the same pallas_call at the per-device
         bucket shape — one trace, every device."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
+        jax = self._jax
         self.traces += 1
         rows, M = mem.shape
         bucket = rows // self.n_devices
-        call = make_cgra_call(self.linked, M=M, bB=bucket, n_tiles=1,
-                              interpret=self.interpret)
+        call = make_cgra_call(self.linked, M=M, bB=bucket, n_tiles=1)
 
-        def shard_fn(niter, mem_shard):
-            return call(niter, *self._tables, mem_shard.T).T
+        def shard_fn(niter, mem_shard, *tables):
+            return call(niter, *tables, mem_shard.T).T
 
-        return shard_map(shard_fn, mesh=self.mesh,
-                         in_specs=(P(), P(self.axis, None)),
-                         out_specs=P(self.axis, None),
-                         check_rep=False)(niter, mem)
+        tables = self._tables
+        return jax.shard_map(
+            shard_fn, mesh=self.mesh,
+            in_specs=(P(), P(self.axis, None)) + (P(),) * len(tables),
+            out_specs=P(self.axis, None),
+            check_vma=False)(niter, mem, *tables)
 
     # -- the sharded block plan ----------------------------------------------
     def _capacity(self) -> int:
@@ -536,7 +546,7 @@ class ShardedKernelEngine(KernelEngine):
 
 class CompiledKernelCache:
     """The engine registry: one ``KernelEngine`` per
-    ``(lowered fingerprint, lanes, interpret, placement)``, created on
+    ``(lowered fingerprint, lanes, placement)``, created on
     first use and kept for the life of the process — the
     trace-once/run-many cache the pallas backend, ``Executable.warmup``
     and the execution service share.  Placement distinguishes the default
@@ -546,7 +556,7 @@ class CompiledKernelCache:
 
     def __init__(self, buckets: Optional[Sequence[int]] = None) -> None:
         self.default_buckets = buckets
-        self._engines: Dict[Tuple[str, int, bool, Optional[str]],
+        self._engines: Dict[Tuple[str, int, Optional[str]],
                             KernelEngine] = {}
         self._lock = threading.Lock()
 
@@ -560,68 +570,61 @@ class CompiledKernelCache:
         return None if device is None else f"dev:{device.id}"
 
     def engine_for(self, linked: LinkedConfig, *, lanes: int = 128,
-                   interpret: bool = True,
                    buckets: Optional[Sequence[int]] = None,
                    device=None) -> KernelEngine:
-        key = (lowered_fingerprint(linked), lanes, interpret,
+        key = (lowered_fingerprint(linked), lanes,
                self._placement(device, None, False))
         with self._lock:
             eng = self._engines.get(key)
             if eng is None:
-                eng = KernelEngine(linked, lanes=lanes, interpret=interpret,
+                eng = KernelEngine(linked, lanes=lanes,
                                    buckets=buckets or self.default_buckets,
                                    device=device)
                 self._engines[key] = eng
             return eng
 
     def sharded_engine_for(self, linked: LinkedConfig, *, lanes: int = 128,
-                           interpret: bool = True,
                            buckets: Optional[Sequence[int]] = None,
                            mesh=None) -> ShardedKernelEngine:
         """The multi-device engine for ``linked`` (default mesh: every
         host device on a 1-D ``data`` axis), cached like ``engine_for``."""
-        key = (lowered_fingerprint(linked), lanes, interpret,
+        key = (lowered_fingerprint(linked), lanes,
                self._placement(None, mesh, True))
         with self._lock:
             eng = self._engines.get(key)
             if eng is None:
                 eng = ShardedKernelEngine(
-                    linked, lanes=lanes, interpret=interpret,
+                    linked, lanes=lanes,
                     buckets=buckets or self.default_buckets, mesh=mesh)
                 self._engines[key] = eng
             return eng
 
     def run(self, linked: LinkedConfig, flats: np.ndarray, n_iters: int, *,
-            lanes: int = 128, interpret: bool = True, device=None
+            lanes: int = 128, device=None
             ) -> Tuple[np.ndarray, Dict[str, object]]:
-        eng = self.engine_for(linked, lanes=lanes, interpret=interpret,
-                              device=device)
+        eng = self.engine_for(linked, lanes=lanes, device=device)
         return eng.run(flats, n_iters)
 
     def sharded_run(self, linked: LinkedConfig, flats: np.ndarray,
-                    n_iters: int, *, lanes: int = 128,
-                    interpret: bool = True, mesh=None
+                    n_iters: int, *, lanes: int = 128, mesh=None
                     ) -> Tuple[np.ndarray, Dict[str, object]]:
-        eng = self.sharded_engine_for(linked, lanes=lanes,
-                                      interpret=interpret, mesh=mesh)
+        eng = self.sharded_engine_for(linked, lanes=lanes, mesh=mesh)
         return eng.run(flats, n_iters)
 
     def run_stream(self, linked: LinkedConfig, source, n_iters: int, *,
                    chunk: Optional[int] = None, depth: int = 2,
-                   lanes: int = 128, interpret: bool = True, device=None
+                   lanes: int = 128, device=None
                    ) -> Iterator[Tuple[np.ndarray, Dict[str, object]]]:
         """Streaming execution through the cached engine for ``linked``
         (see ``KernelEngine.run_stream``); yields drained chunks, returns
         the stream summary via ``StopIteration.value``."""
-        eng = self.engine_for(linked, lanes=lanes, interpret=interpret,
-                              device=device)
+        eng = self.engine_for(linked, lanes=lanes, device=device)
         return eng.run_stream(source, n_iters, chunk=chunk, depth=depth)
 
     def warmup(self, linked: LinkedConfig, M: int, *,
                buckets: Optional[Sequence[int]] = None, lanes: int = 128,
-               interpret: bool = True, device=None) -> Dict[str, object]:
-        eng = self.engine_for(linked, lanes=lanes, interpret=interpret,
-                              device=device)
+               device=None) -> Dict[str, object]:
+        eng = self.engine_for(linked, lanes=lanes, device=device)
         return eng.warmup(M, buckets)
 
     def stats(self) -> Dict[str, object]:
@@ -630,8 +633,8 @@ class CompiledKernelCache:
         with self._lock:
             engines = dict(self._engines)
         per = {}
-        for (fp, lanes, it, placement), e in engines.items():
-            name = f"{fp[:12]}/lanes={lanes}/{'interp' if it else 'tpu'}"
+        for (fp, lanes, placement), e in engines.items():
+            name = f"{fp[:12]}/lanes={lanes}/{e.mode}"
             if placement is not None:
                 name += f"/{placement}"
             per[name] = e.stats()

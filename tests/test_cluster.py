@@ -523,3 +523,55 @@ def test_replicated_soak_bounded_queue_and_finite_p99():
     assert max(depths) <= 64, "queue depth must stay bounded"
     assert stats["p99_ms"] is not None and np.isfinite(stats["p99_ms"])
     assert stats["completed"] == completed > 0
+
+
+# ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+def test_router_pins_slots_to_chips_on_tpu(monkeypatch):
+    """On a TPU, replicas without ``devices=`` go one per chip
+    (``jax.devices()[i]``); more replicas than chips is an error."""
+    import jax
+
+    import repro.kernels.cgra_exec.kernel as kernel
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    n = len(jax.devices())
+    router = Router(n)
+    assert [s.device for s in router.slots] == jax.devices()[:n]
+    with pytest.raises(ValueError, match="devices"):
+        Router(n + 1)
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: True)
+    assert [s.device for s in Router(2).slots] == [None, None]
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas_sharded"])
+def test_cluster_service_refuses_device_backend_on_tpu(monkeypatch,
+                                                       backend):
+    """Spawned workers cannot share a chip: on a TPU the cluster front
+    end refuses device backends and points at Service(replicas=...)."""
+    from repro.ual.cluster import service as cluster
+    monkeypatch.setattr(cluster, "_tpu_platform", lambda: True)
+    svc = ual.ClusterService(workers=1, start=False)
+    program = _program()
+    mem = program.random_inputs(np.random.default_rng(0))
+    with pytest.raises(ValueError, match=r"Service\(replicas="):
+        svc.submit(program, _target(backend=backend), mem)
+    cluster._check_backend_off_chip("sim")          # host backends pass
+
+
+def test_tpu_platform_read_without_starting_jax():
+    """The cluster front end asks for the platform without importing JAX
+    (a fresh process: importing the module must not pull JAX in)."""
+    code = ("import os, sys\n"
+            "from repro.ual.cluster.service import _tpu_platform\n"
+            "out = []\n"
+            "for plats in ('cpu', 'tpu', 'tpu,cpu'):\n"
+            "    os.environ['JAX_PLATFORMS'] = plats\n"
+            "    out.append(_tpu_platform())\n"
+            "print('jax' in sys.modules, out)\n")
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120,
+                         check=True).stdout.strip()
+    assert out == "False [False, True, True]"

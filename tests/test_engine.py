@@ -17,6 +17,11 @@ The contract under test:
     scalar paths exactly (including missing / short arrays),
   * ``Service.stats()`` surfaces the engine aggregate.
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -76,8 +81,7 @@ def test_dynamic_n_iters_shares_one_trace(compiled):
     reuse the same trace, and each still matches the oracle."""
     program, exe = compiled
     be = ual.get_backend("pallas")
-    eng = be.engine.engine_for(exe.lowered, lanes=be.lanes,
-                               interpret=be.interpret)
+    eng = be.engine.engine_for(exe.lowered, lanes=be.lanes)
     mems = _mems(program, 4, seed=42)
     exe.run_batch(mems, n_iters=3)           # warm (or reuse) bucket 8
     before = eng.traces
@@ -231,3 +235,72 @@ def test_service_stats_surface_engine_aggregate():
         assert {"engines", "traces", "hit_ratio"} <= set(snap["engine"])
     finally:
         svc.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# platform-chosen execution mode, kernel tables, compile cache placement
+# ---------------------------------------------------------------------------
+
+def test_engine_mode_and_platform_follow_jax_backend(compiled):
+    """No caller picks interpret mode: off a TPU every engine interprets
+    the kernel, and its stats say so."""
+    import jax
+
+    from repro.kernels.cgra_exec.kernel import interpret_mode
+    _, exe = compiled
+    cache = CompiledKernelCache()
+    stats = cache.engine_for(exe.lowered).stats()
+    assert interpret_mode() == (jax.default_backend() != "tpu")
+    assert stats["mode"] == ("interp" if interpret_mode() else "tpu")
+    assert stats["platform"] == jax.default_backend()
+    name, = cache.stats()["per_engine"]
+    assert name.endswith("/" + stats["mode"])
+
+
+@pytest.mark.parametrize("II", [1, 2, 3, 5])
+def test_kernel_tables_iteration_index_is_a_subtraction(II):
+    """``q0`` in the kernel tables turns ``(t - t0) // II`` into ``q - q0``
+    for ``t = q*II + s`` — for every slot, including ``t0`` scheduled
+    off its own slot."""
+    from repro.core.lowering import KV_Q0, LinkedConfig, kernel_tables
+    P = 1
+    t0s = np.arange(0, 4 * II)
+    for t0 in t0s:
+        scalar = np.zeros((II, P, 4), np.int32)
+        scalar[:, :, 0] = 1                        # any non-NOP opcode
+        scalar[:, :, 3] = t0
+        linked = LinkedConfig(
+            II=II, n_pes=P, n_regs=1, mem_pes=(), scalar=scalar,
+            ops=np.zeros((II, P, 3, 5), np.int32),
+            regw=np.zeros((II, P, 1, 3), np.int32))
+        _, vtab, _ = kernel_tables(linked)
+        for s in range(II):
+            q0 = vtab[s, 0, KV_Q0]
+            for q in range(6):
+                t = q * II + s
+                assert q - q0 == (t - t0) // II, (II, t0, s, q)
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_placement(tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself);
+    otherwise the cache goes to the fixed ``<repo>/artifacts/jax_cache``.
+    Run in a fresh process: the placement is process-wide JAX config."""
+    repo = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(repo / "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import jax\n"
+            "from repro.kernels.cgra_exec.kernel import use_compile_cache\n"
+            "print(use_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "print(jax.config.jax_persistent_cache_min_compile_time_secs)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120,
+                         check=True).stdout.split()
+    want = (str(tmp_path / env_dir) if env_dir
+            else str(repo / "artifacts" / "jax_cache"))
+    assert out[0] == out[1] == want
+    assert float(out[2]) < 1.0

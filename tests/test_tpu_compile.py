@@ -1,0 +1,89 @@
+"""Compile-only guard: the ``cgra_exec`` kernel compiles for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached, and it
+compiles for a *described* chip.  These tests compile the kernel of the
+main path at the real width — the default scratchpad (M = 8192) and the
+engine's bucket sizes 8 and 128, on the published fabrics — for one chip
+of a described ``v5e:2x2``, and check that the Mosaic kernel is in the
+compiled program.  Nothing
+runs, so they say nothing about results or times; the interpret-mode
+tests (``test_engine.py`` and friends) hold the semantics.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU runtime, and every test worker imports this
+file.  The persistent compile cache is off around these compiles — an
+entry compiled for a described chip cannot be read back without one.
+"""
+import os
+
+import pytest
+
+from repro import ual
+
+M = 8192
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:              # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+#: the published fabrics: HyCUBE 4x4, N2N 4x4 and PACE 8x8
+FABRICS = {"hycube": dict(rows=4, cols=4), "n2n": dict(rows=4, cols=4),
+           "pace": {}}
+
+
+@pytest.mark.parametrize("bB", [8, 128])
+@pytest.mark.parametrize("fabric", sorted(FABRICS))
+def test_cgra_exec_compiles_for_v5e(fabric, bB, one_chip,
+                                    no_persistent_cache, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.lowering import kernel_tables
+    from repro.kernels.cgra_exec import kernel
+
+    # the platform here is the CPU: steer the kernel to its TPU branch,
+    # and keep the persistent cache where the fixture put it
+    monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kernel, "use_compile_cache", lambda: None)
+
+    program = ual.Program.from_kernel("gemm")
+    assert program.layout.total_words == M
+    exe = ual.compile(program, ual.Target.from_name(
+        fabric, backend="pallas", **FABRICS[fabric]))
+    assert exe.success
+    call = kernel.make_cgra_call(exe.lowered, M=M, bB=bB)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+    args = ([shape(1, 1)]
+            + [shape(*t.shape) for t in kernel_tables(exe.lowered)]
+            + [shape(M, bB)])
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
