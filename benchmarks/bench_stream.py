@@ -193,7 +193,7 @@ def run(seed: int = 0, verbose: bool = True) -> dict:
         ual.set_default_engine(prev_engine)
 
     # -- trace artifact: one streaming sweep with the flight recorder on,
-    # exported next to the claims JSON so the upload/compute/drain
+    # exported next to the claims JSON so the upload/wait/download
     # pipeline is inspectable at https://ui.perfetto.dev
     tracer = obs.Tracer(enabled=True)
     prev = obs.set_tracer(tracer)
@@ -206,8 +206,10 @@ def run(seed: int = 0, verbose: bool = True) -> dict:
                 except StopIteration:
                     break
         trace_path = tracer.export_chrome(ART / "stream_trace.json")
-        chunk_spans = sum(1 for s in tracer.spans()
-                          if s.name.startswith("stream:"))
+        stage_spans: dict = {}
+        for s in tracer.spans():
+            if s.name.startswith("stream:"):
+                stage_spans[s.name] = stage_spans.get(s.name, 0) + 1
     finally:
         obs.set_tracer(prev)
 
@@ -228,7 +230,9 @@ def run(seed: int = 0, verbose: bool = True) -> dict:
                     "discrete_requests": SERVICE_DISCRETE_N,
                     "parity": svc_parity, "stats": svc_stats,
                     "stream_info": stream_info},
-        "trace": {"file": str(trace_path), "chunk_spans": chunk_spans},
+        "trace": {"file": str(trace_path),
+                  "chunk_spans": sum(stage_spans.values()),
+                  "stage_spans": stage_spans},
     }
     claims = {
         "mapped": True,
@@ -239,6 +243,10 @@ def run(seed: int = 0, verbose: bool = True) -> dict:
         "no_new_traces_while_streaming":
             traces_after_stream == traces_after_warmup,
         "service_stream_parity_with_interleaved_discrete": svc_parity,
+        # the engine's three stages, once per chunk of the traced sweep
+        "trace_upload_wait_download_per_chunk": stage_spans == {
+            name: B_TOTAL // CHUNK for name in
+            ("stream:upload", "stream:wait", "stream:download")},
         "service_stream_stats_surfaced":
             svc_stats["spans"] > 0 and svc_stats["samples"]
             == SERVICE_STREAM_N,

@@ -318,6 +318,7 @@ def make_cgra_call(linked: LinkedConfig, *, M: int, bB: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit_bytes(M, bB)),
         interpret=interpret,
+        name="cgra_exec",
     )
 
 

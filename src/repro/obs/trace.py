@@ -25,6 +25,13 @@ aggregate half).  Design constraints, in order:
      compact entries (:meth:`Tracer.record_tree`) and ``Span`` objects
      only materialize on the read side.
 
+A context-manager span of an enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` of its name while it is open, once JAX
+has been imported by someone else (this module never imports it): inside
+a ``jax.profiler`` session the span lands in the profiler's host plane,
+on the same clock as the device's ops.  Retrospective spans
+(:meth:`Tracer.record` / :meth:`Tracer.record_tree`) reach the ring only.
+
 Spans export as Chrome trace-event JSON (``ph:"X"`` complete events plus
 ``ph:"M"`` track-name metadata) — load the file at https://ui.perfetto.dev
 or ``chrome://tracing``.  ``python -m repro.obs.trace`` is the CLI: it
@@ -35,6 +42,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -122,12 +130,24 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` when JAX is
+    already loaded, else None: the span's second sink, never a reason to
+    import JAX."""
+    prof = sys.modules.get("jax.profiler")
+    ann = getattr(prof, "TraceAnnotation", None)
+    return ann(name) if ann is not None else None
+
+
 class _ActiveSpan:
     """A live context-manager span: pushed on the owning tracer's
     thread-local stack on ``__enter__`` (so children find their parent),
-    recorded on ``__exit__``."""
+    recorded on ``__exit__``.  While open it also holds the profiler
+    annotation of the same name (``_profiler_annotation``).  Each end's
+    clock is read just after the annotation's own stamp, so both sinks
+    give the span the same duration to within a microsecond."""
     __slots__ = ("_tracer", "name", "cat", "trace_id", "span_id",
-                 "parent_id", "args", "_t0")
+                 "parent_id", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: Optional[str], parent_id: Optional[str],
@@ -139,6 +159,7 @@ class _ActiveSpan:
         self.parent_id = parent_id
         self.args = dict(args) if args else {}
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **kwargs) -> None:
         """Attach attributes to the span while it is open."""
@@ -157,10 +178,15 @@ class _ActiveSpan:
                 self.trace_id = tr.new_trace_id()
         self.span_id = tr.new_span_id()
         stack.append(self)
+        self._ann = _profiler_annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         t1 = time.perf_counter()
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
@@ -235,8 +261,11 @@ class Tracer:
              trace: Optional[str] = None, parent: Optional[str] = None,
              args: Optional[dict] = None):
         """Context-manager span.  Nested uses inherit trace/parent from the
-        enclosing span on this thread.  When the tracer is disabled this
-        returns a shared no-op singleton (no clock read, no allocation)."""
+        enclosing span on this thread; open and close it on one thread.
+        Enabled, it also annotates the JAX profiler's trace while open
+        (see the module docstring).  When the tracer is disabled this
+        returns a shared no-op singleton (no clock read, no allocation,
+        no annotation)."""
         if not self.enabled:
             return _NULL_SPAN
         return _ActiveSpan(self, name, cat, trace, parent, args)
@@ -272,16 +301,6 @@ class Tracer:
             self._recorded += 1
             if evicted is not None:
                 self._dropped += _entry_weight(evicted)
-
-    def record_many(self, spans: Iterable[Span]) -> None:
-        """Record pre-built spans under ONE lock acquisition — the bulk
-        producer API for paths that materialize several spans at once."""
-        with self._lock:
-            for s in spans:
-                evicted = self._buf.append(s)
-                self._recorded += 1
-                if evicted is not None:
-                    self._dropped += _entry_weight(evicted)
 
     def record_tree(self, trace_id: str, items, *,
                     track: Optional[str] = None) -> None:

@@ -30,6 +30,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.dfg import interpret
 from repro.core.mapper import MapResult
 from repro.ual.program import Program
@@ -236,23 +237,31 @@ class PallasBackend(Backend):
         *i* computes on device, the host flattens/uploads chunk *i+1*
         and unflattens chunk *i-1*'s drained rows.  Same bucket-ladder
         traces as ``execute_batch``; the summary carries the engine's
-        measured ``overlap_frac``."""
+        measured ``overlap_frac``.  With the tracer on, each block adds
+        ``stream:flatten`` and ``stream:unflatten`` spans to the engine's
+        stream trace."""
         linked = _ensure_lowered(result, lowered)
         eng = self._engine_for(linked, device=device)
         step = (max(1, min(int(chunk), eng._capacity())) if chunk
                 else eng._capacity())
+        tr = obs.tracer()
+        trace = tr.new_trace_id() if tr.enabled else None
+
+        def flatten(group):
+            with tr.span("stream:flatten", "engine", trace=trace):
+                return program.flatten_batch(group)
 
         def blocks():
             group = []
             for m in mems:
                 group.append(m)
                 if len(group) >= step:
-                    yield program.flatten_batch(group)
+                    yield flatten(group)
                     group = []
             if group:
-                yield program.flatten_batch(group)
+                yield flatten(group)
 
-        gen = eng.run_stream(blocks(), n_iters, chunk=step)
+        gen = eng.run_stream(blocks(), n_iters, chunk=step, trace=trace)
         while True:
             try:
                 out, cinfo = next(gen)
@@ -260,7 +269,9 @@ class PallasBackend(Backend):
                 summary = dict(stop.value or {})
                 summary["batched"] = True
                 return summary
-            yield program.unflatten_batch(out), cinfo
+            with tr.span("stream:unflatten", "engine", trace=trace):
+                outs = program.unflatten_batch(out)
+            yield outs, cinfo
 
     def warmup(self, program, result, lowered=None, buckets=None,
                device=None):

@@ -292,7 +292,7 @@ class KernelEngine:
 
     def run_stream(self, source: Union[np.ndarray, Iterable[np.ndarray]],
                    n_iters: int, *, chunk: Optional[int] = None,
-                   depth: int = 2
+                   depth: int = 2, trace: Optional[str] = None
                    ) -> Iterator[Tuple[np.ndarray, Dict[str, object]]]:
         """Streaming execution: pipeline warm-bucket chunks with double
         buffering, yielding ``(out_chunk (b, M), chunk_info)`` as each
@@ -314,6 +314,11 @@ class KernelEngine:
         the host spent preparing/draining other chunks while the device
         worked.  A fully serialized pipeline (or an empty stream)
         reports 0.0.
+
+        With the tracer on, each block records ``stream:upload`` (pad +
+        dispatch), ``stream:wait`` (blocked on the device: the intervals
+        ``wait_s`` sums) and ``stream:download`` (the copy back), all
+        under one trace id per stream: ``trace``, or a new one.
         """
         jnp = self._jnp
         if depth < 1:
@@ -340,47 +345,38 @@ class KernelEngine:
         tr = obs.tracer()
         tron = tr.enabled
         # one trace groups every chunk span of this stream in the export
-        stream_trace = tr.new_trace_id() if tron else None
-        inflight: deque = deque()  # (future, b, rows, was_cold, t_disp, i)
+        if tron and trace is None:
+            trace = tr.new_trace_id()
+        inflight: deque = deque()  # (future, b, rows, was_cold)
 
         def drain() -> Tuple[np.ndarray, Dict[str, object]]:
             nonlocal wait_s, cold_blocks, n_samples, n_chunks
-            fut, b, rows, was_cold, t_disp, i_chunk = inflight.popleft()
-            t0 = time.perf_counter()
-            fut.block_until_ready()
-            t1 = time.perf_counter()
-            wait_s += t1 - t0
-            out = np.asarray(fut)[:b]
+            fut, b, rows, was_cold = inflight.popleft()
+            with tr.span("stream:wait", "engine", trace=trace):
+                t0 = time.perf_counter()
+                fut.block_until_ready()
+                wait_s += time.perf_counter() - t0
+            with tr.span("stream:download", "engine", trace=trace):
+                out = np.asarray(fut)[:b]
             cold_blocks += was_cold
             used.append(rows)
             n_samples += b
             n_chunks += 1
-            if tron:
-                # device-busy window approximated from dispatch end to
-                # ready; drain = host-side conversion back to numpy
-                attrs = {"chunk": i_chunk, "bucket": rows, "samples": b}
-                tr.record("stream:compute", t_disp, t1, cat="engine",
-                          trace=stream_trace, args=attrs)
-                tr.record("stream:drain", t1, time.perf_counter(),
-                          cat="engine", trace=stream_trace, args=attrs)
             return out, {"chunk": n_chunks - 1, "bucket": rows,
                          "samples": b, "traced": int(was_cold)}
 
         for blk in blocks():
             b = blk.shape[0]
-            t_up = time.perf_counter() if tron else 0.0
-            rows = self._block_rows(b)
-            if rows != b:
-                blk = np.concatenate(
-                    [blk, np.zeros((rows - b, blk.shape[1]), np.int32)])
-            fut, was_cold = self._dispatch_block(blk, niter)
-            t_disp = time.perf_counter() if tron else 0.0
-            if tron:
-                tr.record("stream:upload", t_up, t_disp, cat="engine",
-                          trace=stream_trace,
-                          args={"chunk": n_dispatched, "bucket": rows,
-                                "samples": b, "traced": int(was_cold)})
-            inflight.append((fut, b, rows, was_cold, t_disp, n_dispatched))
+            with tr.span("stream:upload", "engine", trace=trace) as sp:
+                rows = self._block_rows(b)
+                if rows != b:
+                    blk = np.concatenate(
+                        [blk, np.zeros((rows - b, blk.shape[1]), np.int32)])
+                fut, was_cold = self._dispatch_block(blk, niter)
+                if tron:
+                    sp.set(chunk=n_dispatched, bucket=rows, samples=b,
+                           traced=int(was_cold))
+            inflight.append((fut, b, rows, was_cold))
             n_dispatched += 1
             while len(inflight) > depth:
                 yield drain()

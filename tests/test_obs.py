@@ -27,6 +27,8 @@ Contract under test:
     per-stage breakdown accounts for the reported request latency.
 """
 import json
+import os
+import sys
 import threading
 
 import numpy as np
@@ -159,6 +161,110 @@ def test_disabled_span_is_shared_singleton_with_no_capture(monkeypatch):
         s.set(ignored=True)
     assert allocs == []                   # no Span ever constructed
     assert tr.spans() == [] and tr.stats()["recorded"] == 0
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs its events."""
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    import types
+    mod = types.ModuleType("jax.profiler")
+    mod.TraceAnnotation = _FakeAnnotation
+    monkeypatch.setitem(sys.modules, "jax.profiler", mod)
+    _FakeAnnotation.log = []
+    return _FakeAnnotation.log
+
+
+def test_enabled_span_annotates_the_profiler_while_open(fake_profiler):
+    tr = obs.Tracer(enabled=True)
+    with tr.span("stream:outer"):
+        with tr.span("stream:inner"):
+            assert fake_profiler == [("enter", "stream:outer"),
+                                     ("enter", "stream:inner")]
+    assert fake_profiler[2:] == [("exit", "stream:inner"),
+                                 ("exit", "stream:outer")]
+    assert [s.name for s in tr.spans()] == ["stream:inner", "stream:outer"]
+
+
+def test_disabled_and_retrospective_spans_annotate_nothing(fake_profiler):
+    tr = obs.Tracer(enabled=False)
+    with tr.span("off"):
+        pass
+    tr.enable()
+    tr.record("after-the-fact", 1.0, 2.0)
+    tr.record_tree(tr.new_trace_id(), (("request", 1.0, 2.0, "s", None),))
+    assert fake_profiler == []
+    assert len(tr.spans()) == 2
+
+
+def test_span_without_jax_loaded_reaches_the_ring(monkeypatch):
+    monkeypatch.delitem(sys.modules, "jax.profiler", raising=False)
+    tr = obs.Tracer(enabled=True)
+    with tr.span("ring-only"):
+        pass
+    assert [s.name for s in tr.spans()] == ["ring-only"]
+
+
+def test_spans_land_in_the_jax_profiler_trace(tmp_path):
+    """Inside a profiler session an enabled span is a host event of the
+    trace, on the profiler's clock, with the ring's duration (compared
+    as medians: a thread switch between the annotation's stamp and the
+    ring's read lengthens one side of a single span)."""
+    import glob
+    import statistics
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = obs.Tracer(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(7):
+            with tr.span("stream:probe"):
+                sum(range(20000))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    got = [(e.end_ns - e.start_ns) / 1e9
+           for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name == "stream:probe"]
+    ring = [s.dur_s for s in tr.spans()]
+    assert len(got) == len(ring) == 7
+    assert statistics.median(got) == pytest.approx(
+        statistics.median(ring), rel=0.05, abs=5e-6)
+
+
+def test_obs_spans_never_import_jax():
+    """``repro.obs`` annotates the profiler only when someone else has
+    loaded JAX; its own spans never load it (a fresh process)."""
+    import subprocess
+    from pathlib import Path
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "tr = obs.Tracer(enabled=True)\n"
+            "with tr.span('stream:x'):\n"
+            "    pass\n"
+            "print('jax' in sys.modules, len(tr.spans()))\n")
+    src = Path(__file__).resolve().parents[1] / "src"
+    out = subprocess.run([sys.executable, "-c", code],
+                         env={**os.environ, "PYTHONPATH": str(src)},
+                         capture_output=True, text=True, timeout=120,
+                         check=True).stdout.strip()
+    assert out == "False 1"
 
 
 def test_disabled_service_attaches_no_trace_info(fresh_obs):
