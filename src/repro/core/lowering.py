@@ -11,9 +11,9 @@ engine ever routes dynamically:
   * the vectorized batched simulator (``core.simulator.simulate_batch``)
     turns operand fetch into static numpy gathers over the PE-output /
     register state,
-  * the Pallas ``cgra_exec`` TPU kernel turns it into one-hot
-    compare/select reductions over the same state (the TPU-native
-    analogue of the clockless-repeater bypass).
+  * the Pallas ``cgra_exec`` TPU kernel turns it into per-slot lists of
+    addressed row copies out of the same state, only the rows the slot
+    routes (the TPU-native analogue of the clockless-repeater bypass).
 
 The ``ual`` compile pipeline runs this as its ``lowering`` pass and
 memoizes the result in the mapping cache next to the ``MapResult``,
@@ -97,38 +97,46 @@ class LinkedConfig:
 # Field layout of the Pallas kernel's tables (``kernel_tables``).
 # Per-PE vector table, one (P, KV_FIELDS) row block per slot:
 KV_OPC, KV_CONST, KV_T0OK, KV_LIVE, KV_Q0 = 0, 1, 2, 3, 4
-#: operand k occupies fields KV_OP + 5k .. KV_OP + 5k + 4:
-#: [gather source, is-immediate, dist, init, takes-trailing-immediate]
+#: operand k occupies fields KV_OP + 4k .. KV_OP + 4k + 3:
+#: [is-immediate, dist, init, takes-trailing-immediate]
 KV_OP = 5
-KV_FIELDS = KV_OP + 15
-# Register-write table, one (P*R, KR_FIELDS) row block per slot:
-#: [move source, result source PE, its live flag, its q0]
-KR_MOVE, KR_RES, KR_RES_LIVE, KR_RES_Q0 = 0, 1, 2, 3
-KR_FIELDS = 4
+KV_FIELDS = KV_OP + 12
 # Scalar (SMEM) table, KS_FIELDS words per (slot, memory PE):
 KS_OPC, KS_CONST, KS_LIVE, KS_Q0, KS_HAS_IDX, KS_HAS2 = 0, 1, 2, 3, 4, 5
 KS_FIELDS = 6
+# Row-copy (SMEM) table: a KC_HEAD-word header per slot, [count, offset]
+# of each of its three lists, then the lists' entries.
+#: the lists: operand copies, register moves, result writes
+KC_OPS, KC_MOVES, KC_RES = 0, 1, 2
+KC_HEAD = 6
+#: words per entry: operand copy [operand-block row k*P + pe, source row
+#: in [O; R]]; register move [register row, source row in [O; R]];
+#: result write [register row, source PE, its live flag, its q0]
+KC_WIDTH = (2, 2, 4)
 
 
 def kernel_tables(linked: LinkedConfig
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """The dense tables re-laid out for the Pallas kernel: ``(stab, vtab,
-    rtab)``.
+    ctab)``.
 
     The kernel keeps every table 2-D per slot with a wide minor axis (the
     TPU's (8, 128) tiling refuses the raw tables' minor dims of 3 and 5),
-    reads memory-PE fields as scalars, and does no vector integer
-    division.  So everything static is folded here, once:
+    reads memory-PE fields and row addresses as scalars, and does no
+    vector integer division.  So everything static is folded here, once:
 
-      * ``vtab`` (S, P, KV_FIELDS): per-PE columns.  Operand sources index
-        the stacked ``[O; R]`` state (``pe`` for K_O, ``P + pe*R + reg``
-        for K_R, -1 otherwise).  ``q0`` makes the iteration index a
-        subtraction: with ``t = q*II + s``, ``(t - t0) // II == q - q0``.
-      * ``rtab`` (S, P*R, KR_FIELDS): per-register-row writes, with the
-        source PE's ``live``/``q0`` copied in so ``fired[src]`` needs no
-        gather.
+      * ``vtab`` (S, P, KV_FIELDS): per-PE columns.  ``q0`` makes the
+        iteration index a subtraction: with ``t = q*II + s``,
+        ``(t - t0) // II == q - q0``.
       * ``stab`` (S * n_mem * KS_FIELDS,) int32: the memory PEs' fields,
         flat for scalar memory.
+      * ``ctab`` (flat int32, scalar memory): the rows each slot routes,
+        as lists of row copies (``KC_*``).  Source rows index the stacked
+        ``[O; R]`` state (``pe`` for K_O, ``P + pe*R + reg`` for K_R); an
+        operand with no such source is absent and reads 0; a register
+        row with no move or result write keeps its value.  A result
+        write carries its source PE's ``live``/``q0``, so whether it
+        fires is a scalar test.
 
     Memoized on the instance (underscore attribute: never pickled).
     """
@@ -136,7 +144,7 @@ def kernel_tables(linked: LinkedConfig
     if cached is not None:
         return cached
     S, P, R = linked.II, linked.n_pes, linked.n_regs
-    sc, ops, rw = linked.scalar, linked.ops, linked.regw
+    sc, ops = linked.scalar, linked.ops
     opc, const, use_c, t0 = (sc[..., i] for i in range(4))
     t0ok = t0 >= 0
     live = (opc != OPC["NOP"]) & t0ok
@@ -157,25 +165,13 @@ def kernel_tables(linked: LinkedConfig
     kinds = ops[..., 0]
     n_ops = (kinds != K_NONE).sum(axis=-1)
     for k in range(3):
-        kind, pe, reg, dist, init = (ops[:, :, k, i] for i in range(5))
-        b = KV_OP + 5 * k
-        vtab[..., b] = src(kind, pe, reg)
-        vtab[..., b + 1] = kind == K_CONST
-        vtab[..., b + 2] = dist
-        vtab[..., b + 3] = init
+        kind, dist, init = ops[:, :, k, 0], ops[:, :, k, 3], ops[:, :, k, 4]
+        b = KV_OP + 4 * k
+        vtab[..., b] = kind == K_CONST
+        vtab[..., b + 1] = dist
+        vtab[..., b + 2] = init
         # the immediate is a *trailing* ALU operand when use_const is set
-        vtab[..., b + 4] = (kind == K_NONE) & (use_c != 0) & (n_ops == k)
-
-    rk, rp, rr = rw[..., 0], rw[..., 1], rw[..., 2]          # (S, P, R)
-    is_res = rk == K_RESULT
-    rtab = np.zeros((S, P, R, KR_FIELDS), np.int32)
-    rtab[..., KR_MOVE] = src(rk, rp, rr)
-    rtab[..., KR_RES] = np.where(is_res, rp, -1)
-    rtab[..., KR_RES_LIVE] = is_res & np.take_along_axis(live, rp.reshape(
-        S, -1), axis=1).reshape(S, P, R)
-    rtab[..., KR_RES_Q0] = np.take_along_axis(q0, rp.reshape(S, -1),
-                                              axis=1).reshape(S, P, R)
-    rtab = rtab.reshape(S, P * R, KR_FIELDS)
+        vtab[..., b + 3] = (kind == K_NONE) & (use_c != 0) & (n_ops == k)
 
     mp = list(linked.mem_pes)
     stab = np.zeros((S, len(mp), KS_FIELDS), np.int32)
@@ -188,8 +184,42 @@ def kernel_tables(linked: LinkedConfig
         stab[..., KS_HAS2] = ops[:, mp, 1, 0] != K_NONE
     # scalar memory cannot hold an empty array
     stab = stab.reshape(-1) if stab.size else np.zeros(1, np.int32)
-    linked._kernel_tables = (stab, vtab, rtab)
+
+    # operand-block row k*P + pe, and register row p*R + r, of each source
+    op_src = np.stack([src(*(ops[:, :, k, i] for i in range(3)))
+                       for k in range(3)], axis=1).reshape(S, 3 * P)
+    rk, rp, rr = (linked.regw[..., i].reshape(S, P * R) for i in range(3))
+    move_src = src(rk, rp, rr)
+    head = np.zeros((S, 3, 2), np.int64)
+    body, at = [], S * KC_HEAD
+    for s in range(S):
+        opd, mvd, rsd = (np.flatnonzero(op_src[s] >= 0),
+                         np.flatnonzero(move_src[s] >= 0),
+                         np.flatnonzero(rk[s] == K_RESULT))
+        pe = rp[s, rsd]
+        lists = (np.stack([opd, op_src[s, opd]], axis=1),
+                 np.stack([mvd, move_src[s, mvd]], axis=1),
+                 np.stack([rsd, pe, live[s, pe], q0[s, pe]], axis=1))
+        for j, entries in enumerate(lists):
+            head[s, j] = len(entries), at
+            body.append(entries.reshape(-1))
+            at += entries.size
+    ctab = np.concatenate([head.reshape(-1)] + body).astype(np.int32)
+    linked._kernel_tables = (stab, vtab, ctab)
     return linked._kernel_tables
+
+
+def state_copy_counts(linked: LinkedConfig) -> Tuple[int, int]:
+    """``(copied, dense)`` PE-state rows per round of the II slots: the
+    rows the kernel's copy lists move (``ctab``), and the rows a dense
+    one-hot scan steps through — each of the three operands and the
+    register moves over all ``N = P + P*R`` state rows, the result writes
+    over the ``P`` results."""
+    ctab = kernel_tables(linked)[2]
+    S, P, R = linked.II, linked.n_pes, linked.n_regs
+    head = ctab[:S * KC_HEAD].reshape(S, 3, 2)
+    n = P + P * R
+    return int(head[..., 0].sum()), S * (4 * n + P)
 
 
 def lowered_fingerprint(linked: LinkedConfig) -> str:

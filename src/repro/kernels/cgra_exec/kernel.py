@@ -12,9 +12,10 @@ so a 1:1 port would waste the TPU.  Instead:
     for the whole kernel — the "CM stays on-chip" analogue: per-PE
     columns in VMEM, the memory PEs' fields as scalars in SMEM;
   * HyCUBE's single-cycle multi-hop routes were resolved at link time
-    (``core.lowering``), so operand fetch is a static select chain over
-    the stacked ``[O; R]`` PE state — compiler-scheduled routing with
-    zero dynamic-routing hardware, exactly the paper's bet;
+    (``core.lowering``), so operand fetch and register writes are lists
+    of addressed row copies out of the stacked ``[O; R]`` PE state, only
+    the rows each slot routes — compiler-scheduled routing with zero
+    dynamic-routing hardware, exactly the paper's bet;
   * the scratchpad lives in VMEM as an (M, lanes) block; LOAD/STORE are
     data-dependent per lane and become compare/select passes over it
     (TPU has no per-lane gather; this is the idiomatic replacement), run
@@ -49,11 +50,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.lowering import (KR_MOVE, KR_RES, KR_RES_LIVE, KR_RES_Q0,
-                                 KS_CONST, KS_FIELDS, KS_HAS2, KS_HAS_IDX,
-                                 KS_LIVE, KS_OPC, KS_Q0, KV_CONST, KV_LIVE,
-                                 KV_OP, KV_OPC, KV_Q0, KV_T0OK, LinkedConfig,
-                                 kernel_tables)
+from repro.core.lowering import (KC_HEAD, KC_MOVES, KC_OPS, KC_RES,
+                                 KC_WIDTH, KS_CONST, KS_FIELDS, KS_HAS2,
+                                 KS_HAS_IDX, KS_LIVE, KS_OPC, KS_Q0, KV_CONST,
+                                 KV_LIVE, KV_OP, KV_OPC, KV_Q0, KV_T0OK,
+                                 LinkedConfig, kernel_tables)
 from repro.core.machine import OPC
 
 I32 = jnp.int32
@@ -89,15 +90,17 @@ def use_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-def _gather(st_ref, idx, lo: int, n: int):
-    """Rows ``idx`` (X, 1) of ``st_ref[lo:lo + n]`` -> (X, B); an index
-    outside ``[0, n)`` (-1: no source) selects 0.  One select per source
-    row, in a loop: the TPU has no per-row vector gather, and an unrolled
-    chain would keep every broadcast row live at once."""
-    def body(r, out):
-        return jnp.where(idx == r, st_ref[pl.ds(lo + r, 1), :], out)
-    return jax.lax.fori_loop(
-        0, n, body, jnp.zeros((idx.shape[0], st_ref.shape[1]), I32))
+def _copies(ctab_ref, s, lst: int, copy):
+    """``copy(e)`` for each entry of slot ``s``'s list ``lst`` in the
+    row-copy table, ``e`` the entry's first word: a loop as long as the
+    list, so the work follows the rows the slot routes."""
+    h = s * KC_HEAD + 2 * lst
+    at, width = ctab_ref[h + 1], KC_WIDTH[lst]
+
+    def body(i, carry):
+        copy(at + i * width)
+        return carry
+    jax.lax.fori_loop(0, ctab_ref[h], body, 0)
 
 
 def _lanes(flag, B: int):
@@ -160,9 +163,9 @@ def _mem_passes(mem_ref, chunk: int):
     return load, store
 
 
-def _cgra_kernel(niter_ref, stab_ref, vtab_ref, rtab_ref, mem_in_ref,
-                 mem_out_ref, st_ref, *, II: int, n_pes: int, n_regs: int,
-                 mem_pes, t_max: int, chunk: int):
+def _cgra_kernel(niter_ref, stab_ref, vtab_ref, ctab_ref, mem_in_ref,
+                 mem_out_ref, st_ref, opnd_ref, reg_ref, *, II: int,
+                 n_pes: int, n_regs: int, mem_pes, t_max: int, chunk: int):
     P, R = n_pes, n_regs
     N = P + P * R             # st_ref rows: [O; R] state, then the results
     M, B = mem_out_ref.shape
@@ -182,7 +185,6 @@ def _cgra_kernel(niter_ref, stab_ref, vtab_ref, rtab_ref, mem_in_ref,
         return (live != 0) & (it >= 0) & (it < n_iters), it
 
     def cycle(q, s):
-        O, Rf = st_ref[0:P, :], st_ref[P:N, :]
         tab = vtab_ref[s]                                   # (P, F)
 
         def col(f):
@@ -192,15 +194,23 @@ def _cgra_kernel(niter_ref, stab_ref, vtab_ref, rtab_ref, mem_in_ref,
         it = jnp.where(col(KV_T0OK) != 0, it, 0)
         cvec = jnp.broadcast_to(col(KV_CONST), (P, B))
 
-        # ---- operand fetch: selects over the previous-cycle state ---------
+        # ---- operand fetch: the routed rows of the previous-cycle state,
+        # copied into the (3P, B) operand block; an absent operand reads 0
+        opnd_ref[...] = jnp.zeros(opnd_ref.shape, I32)
+
+        def fetch(e):
+            opnd_ref[pl.ds(ctab_ref[e], 1), :] = \
+                st_ref[pl.ds(ctab_ref[e + 1], 1), :]
+        _copies(ctab_ref, s, KC_OPS, fetch)
+
         def operand(k):
-            b = KV_OP + 5 * k
-            v = _gather(st_ref, col(b), 0, N)
-            v = jnp.where(col(b + 1) != 0, cvec, v)
-            dist = col(b + 2)
+            b = KV_OP + 4 * k
+            v = opnd_ref[k * P:(k + 1) * P, :]
+            v = jnp.where(col(b) != 0, cvec, v)
+            dist = col(b + 1)
             v = jnp.where((dist > 0) & (it < dist),
-                          jnp.broadcast_to(col(b + 3), (P, B)), v)
-            return jnp.where(col(b + 4) != 0, cvec, v)
+                          jnp.broadcast_to(col(b + 2), (P, B)), v)
+            return jnp.where(col(b + 3) != 0, cvec, v)
 
         v0, v1, v2 = operand(0), operand(1), operand(2)
         result = _alu(opc, v0, v1, v2, cvec)
@@ -234,16 +244,24 @@ def _cgra_kernel(niter_ref, stab_ref, vtab_ref, rtab_ref, mem_in_ref,
             result = jnp.where(pe_row == mp, row, result)
 
         # ---- end of cycle: register writes, then output latches -----------
-        rt = rtab_ref[s]                                    # (P*R, F)
+        # the new register file starts as the old one; moves read the old
+        # state, result writes the results of PEs that fired
+        reg_ref[...] = st_ref[P:N, :]
         st_ref[N:N + P, :] = result
-        moved = _gather(st_ref, rt[:, KR_MOVE:KR_MOVE + 1], 0, N)
-        Rf_new = jnp.where(rt[:, KR_MOVE:KR_MOVE + 1] >= 0, moved, Rf)
-        res_fired, _ = fires(rt[:, KR_RES_LIVE:KR_RES_LIVE + 1],
-                             rt[:, KR_RES_Q0:KR_RES_Q0 + 1], q)
-        from_res = _gather(st_ref, rt[:, KR_RES:KR_RES + 1], N, P)
-        Rf_new = jnp.where(res_fired, from_res, Rf_new)
-        st_ref[0:P, :] = jnp.where(fired, result, O)
-        st_ref[P:N, :] = Rf_new
+
+        def move(e):
+            reg_ref[pl.ds(ctab_ref[e], 1), :] = \
+                st_ref[pl.ds(ctab_ref[e + 1], 1), :]
+        _copies(ctab_ref, s, KC_MOVES, move)
+
+        def write(e):
+            @pl.when(fires(ctab_ref[e + 2], ctab_ref[e + 3], q)[0])
+            def _():
+                reg_ref[pl.ds(ctab_ref[e], 1), :] = \
+                    st_ref[pl.ds(N + ctab_ref[e + 1], 1), :]
+        _copies(ctab_ref, s, KC_RES, write)
+        st_ref[0:P, :] = jnp.where(fired, result, st_ref[0:P, :])
+        st_ref[P:N, :] = reg_ref[...]
 
     def round_(q, carry):
         def slot(s, c):
@@ -281,7 +299,7 @@ def make_cgra_call(linked: LinkedConfig, *, M: int, bB: int,
     """Build the ``pallas_call`` executing ``linked`` over ``n_tiles``
     batch tiles of ``bB`` lanes each.
 
-    Returns a callable ``(niter, stab, vtab, rtab, memT) -> memT'`` where
+    Returns a callable ``(niter, stab, vtab, ctab, memT) -> memT'`` where
     ``niter`` is a (1, 1) int32 array (the traced trip count), the tables
     are ``core.lowering.kernel_tables(linked)`` and ``memT`` is the
     (M, n_tiles * bB) transposed scratchpad block.  Everything
@@ -299,7 +317,8 @@ def make_cgra_call(linked: LinkedConfig, *, M: int, bB: int,
         _cgra_kernel, II=linked.II, n_pes=linked.n_pes,
         n_regs=linked.n_regs, mem_pes=linked.mem_pes, t_max=linked.t0_max,
         chunk=_mem_chunk(M))
-    _, vtab, rtab = kernel_tables(linked)
+    _, vtab, _ = kernel_tables(linked)
+    P, R = linked.n_pes, linked.n_regs
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
@@ -308,13 +327,15 @@ def make_cgra_call(linked: LinkedConfig, *, M: int, bB: int,
             smem,
             smem,
             pl.BlockSpec(vtab.shape, lambda i: (0, 0, 0)),
-            pl.BlockSpec(rtab.shape, lambda i: (0, 0, 0)),
+            smem,
             pl.BlockSpec((M, bB), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((M, bB), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((M, n_tiles * bB), I32),
-        scratch_shapes=[pltpu.VMEM(
-            (linked.n_pes * (linked.n_regs + 2), bB), I32)],
+        # [O; R; results], the operand block, the next register file
+        scratch_shapes=[pltpu.VMEM((P * (R + 2), bB), I32),
+                        pltpu.VMEM((3 * P, bB), I32),
+                        pltpu.VMEM((P * R, bB), I32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit_bytes(M, bB)),
         interpret=interpret,

@@ -71,7 +71,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.lowering import (LinkedConfig, kernel_tables,
-                                 lowered_fingerprint)
+                                 lowered_fingerprint, state_copy_counts)
 
 
 def make_cgra_call(*args, **kwargs):
@@ -441,6 +441,7 @@ class KernelEngine:
             }
         calls = sum(bucket_calls.values())
         hits = max(0, calls - traces)
+        copied, dense = state_copy_counts(self.linked)
         return {
             "traces": traces,
             "bucket_calls": bucket_calls,
@@ -450,6 +451,10 @@ class KernelEngine:
             # where the tables (and so every sweep) live
             "platform": ",".join(sorted(
                 {d.platform for d in self._tables[0].devices()})),
+            # PE-state rows the kernel copies per round of the II slots,
+            # against the rows a dense one-hot scan would step through
+            "state_rows_copied_per_round": copied,
+            "state_rows_dense_per_round": dense,
             **snap,
             **self._info_extra(),
         }

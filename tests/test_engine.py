@@ -281,6 +281,98 @@ def test_kernel_tables_iteration_index_is_a_subtraction(II):
                 assert q - q0 == (t - t0) // II, (II, t0, s, q)
 
 
+#: the published fabrics: HyCUBE 4x4, N2N 4x4 and PACE 8x8
+FABRICS = {"hycube": dict(rows=4, cols=4), "n2n": dict(rows=4, cols=4),
+           "pace": {}}
+
+
+@pytest.mark.parametrize("fabric", sorted(FABRICS))
+@pytest.mark.parametrize("kernel_name", ["gemm", "fft"])
+def test_copy_lists_match_dense_gather(kernel_name, fabric):
+    """The kernel's row-copy lists (``ctab``) move exactly what a dense
+    one-hot gather over the whole PE state selects: every operand (an
+    absent source reads 0), every register row (moved, written from a
+    result that fired, or kept), at every round around the firing window.
+    The counter's two numbers are the lists' length and the dense scan's
+    steps."""
+    from repro.core.lowering import (K_O, K_R, K_RESULT, KC_HEAD, KC_MOVES,
+                                     KC_OPS, KC_RES, KC_WIDTH, KV_LIVE,
+                                     KV_Q0, kernel_tables, state_copy_counts)
+    exe = ual.compile(ual.Program.from_kernel(kernel_name),
+                      ual.Target.from_name(fabric, backend="pallas",
+                                           **FABRICS[fabric]))
+    assert exe.success
+    L = exe.lowered
+    S, P, R = L.II, L.n_pes, L.n_regs
+    N, B, n_iters = P + P * R, 3, 2
+    _, vtab, ctab = kernel_tables(L)
+    rng = np.random.default_rng(15)
+    st = rng.integers(1, 2 ** 31, (N + P, B)).astype(np.int32)  # [O; R; res]
+
+    def source(kind, pe, reg):
+        return np.where(kind == K_O, pe,
+                        np.where(kind == K_R, P + pe * R + reg, -1))
+
+    def gather(idx, rows):
+        out = np.zeros((len(idx), B), np.int32)
+        for r in range(len(rows)):
+            out = np.where((idx == r)[:, None], rows[r], out)
+        return out
+
+    head = ctab[:S * KC_HEAD].reshape(S, 3, 2)
+
+    def entries(s, lst):
+        n, at = head[s, lst]
+        return ctab[at:at + n * KC_WIDTH[lst]].reshape(n, KC_WIDTH[lst])
+
+    routed = absent = kept = 0
+    for s in range(S):
+        opnd = np.zeros((3 * P, B), np.int32)
+        for dst, row in entries(s, KC_OPS):
+            opnd[dst] = st[row]
+        for k in range(3):
+            idx = source(*(L.ops[s, :, k, i] for i in range(3)))
+            np.testing.assert_array_equal(opnd[k * P:(k + 1) * P],
+                                          gather(idx, st[:N]))
+            routed += int((idx >= 0).sum())
+            absent += int((idx < 0).sum())
+        rk, rp, rr = L.regw[s].reshape(P * R, 3).T
+        move = source(rk, rp, rr)
+        is_res = rk == K_RESULT
+        moved = st[P:N].copy()
+        for dst, row in entries(s, KC_MOVES):
+            moved[dst] = st[row]
+        routed += int((move >= 0).sum() + is_res.sum())
+        kept += int(((move < 0) & ~is_res).sum())
+        live, q0 = vtab[s, rp, KV_LIVE] != 0, vtab[s, rp, KV_Q0]
+        for q in range(int(q0.min()) - 1, int(q0.max()) + n_iters + 1):
+            got = moved.copy()
+            for dst, pe, e_live, e_q0 in entries(s, KC_RES):
+                if e_live and 0 <= q - e_q0 < n_iters:
+                    got[dst] = st[N + pe]
+            want = np.where((move >= 0)[:, None], gather(move, st[:N]),
+                            st[P:N])
+            fired = is_res & live & (q - q0 >= 0) & (q - q0 < n_iters)
+            want = np.where(fired[:, None],
+                            gather(np.where(is_res, rp, -1), st[N:]), want)
+            np.testing.assert_array_equal(got, want)
+    assert absent and kept          # both defaults are exercised
+    assert state_copy_counts(L) == (routed, S * (4 * N + P))
+    assert int(head[..., 0].sum()) == routed
+
+
+def test_engine_stats_report_state_copies(compiled):
+    """``KernelEngine.stats()`` reports the copy counter, so the
+    ``engine`` source of the metrics registry carries it."""
+    from repro.core.lowering import state_copy_counts
+    _, exe = compiled
+    stats = CompiledKernelCache().engine_for(exe.lowered).stats()
+    copied, dense = state_copy_counts(exe.lowered)
+    assert stats["state_rows_copied_per_round"] == copied
+    assert stats["state_rows_dense_per_round"] == dense
+    assert 0 < copied < dense
+
+
 @pytest.mark.parametrize("env_dir", [None, "given"])
 def test_compile_cache_placement(tmp_path, env_dir):
     """``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself);

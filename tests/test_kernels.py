@@ -175,13 +175,19 @@ def test_cgra_exec_bitexact_hycube(kernel_name):
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("kernel_name", ["gemm", "nw"])
-def test_cgra_exec_bitexact_n2n(kernel_name):
-    from repro.core.adl import n2n
+@pytest.mark.parametrize("fabric,kernel_name", [
+    pytest.param("n2n", "gemm", id="gemm"),
+    pytest.param("n2n", "nw", id="nw"),
+    pytest.param("pace", "fft", id="pace-fft"),
+    pytest.param("pace", "gemm", id="pace-gemm"),
+])
+def test_cgra_exec_bitexact_n2n(fabric, kernel_name):
+    """N2N 4x4 and PACE 8x8 (64 PEs: the benchmark's fft deployment)."""
+    from repro.core.adl import n2n, pace
     from repro.core.dfg import flat_memory
     from repro.kernels.cgra_exec.ops import cgra_exec_op
     from repro.kernels.cgra_exec.ref import cgra_exec_ref
-    fab = n2n(4, 4)
+    fab = n2n(4, 4) if fabric == "n2n" else pace()
     res, layout, mk, n_iters = _mapped(kernel_name, fab)
     rng = np.random.default_rng(6)
     mems = np.stack([flat_memory(layout, mk(rng)) for _ in range(2)])

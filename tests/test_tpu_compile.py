@@ -3,7 +3,8 @@
 The TPU compiler is installed even where no chip is attached, and it
 compiles for a *described* chip.  These tests compile the kernel of the
 main path at the real width — the default scratchpad (M = 8192) and the
-engine's bucket sizes 8 and 128, on the published fabrics — for one chip
+engine's bucket sizes 8 and 128, on the published fabrics, and the
+benchmark's fft on PACE 8x8 at 128 lanes — for one chip
 of a described ``v5e:2x2``, and check that the Mosaic kernel is in the
 compiled program.  Nothing
 runs, so they say nothing about results or times; the interpret-mode
@@ -57,9 +58,14 @@ FABRICS = {"hycube": dict(rows=4, cols=4), "n2n": dict(rows=4, cols=4),
            "pace": {}}
 
 
-@pytest.mark.parametrize("bB", [8, 128])
-@pytest.mark.parametrize("fabric", sorted(FABRICS))
-def test_cgra_exec_compiles_for_v5e(fabric, bB, one_chip,
+#: gemm on every fabric at both widths, and the benchmark's fft on PACE
+CASES = [pytest.param(fabric, bB, "gemm", id=f"{fabric}-{bB}")
+         for fabric in sorted(FABRICS) for bB in (8, 128)] + [
+    pytest.param("pace", 128, "fft", id="pace-128-fft")]
+
+
+@pytest.mark.parametrize("fabric,bB,kernel_name", CASES)
+def test_cgra_exec_compiles_for_v5e(fabric, bB, kernel_name, one_chip,
                                     no_persistent_cache, monkeypatch):
     import jax
     import jax.numpy as jnp
@@ -72,7 +78,7 @@ def test_cgra_exec_compiles_for_v5e(fabric, bB, one_chip,
     monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
     monkeypatch.setattr(kernel, "use_compile_cache", lambda: None)
 
-    program = ual.Program.from_kernel("gemm")
+    program = ual.Program.from_kernel(kernel_name)
     assert program.layout.total_words == M
     exe = ual.compile(program, ual.Target.from_name(
         fabric, backend="pallas", **FABRICS[fabric]))
