@@ -17,6 +17,9 @@ from repro.core.kernel_lib import KERNELS
 from benchmarks.common import fmt_table, save
 
 N_VECTORS = 4
+#: the paper's loop bodies at the repo's trip count; the MachSuite-size
+#: deployments (``fft1024``: 5,120 iterations) belong to the chip benchmark
+TABLE2_KERNELS = tuple(k for k in KERNELS if k != "fft1024")
 
 
 def run(seed: int = 0, verbose: bool = True) -> dict:
@@ -26,7 +29,7 @@ def run(seed: int = 0, verbose: bool = True) -> dict:
                ("n2n4x4", ual.Target.from_name("n2n", rows=4, cols=4,
                                                seed=seed)))
     for fab_name, target in targets:
-        for name in KERNELS:
+        for name in TABLE2_KERNELS:
             program = ual.Program.from_kernel(
                 name, n_banks=target.fabric.n_mem_ports)
             exe = ual.compile(program, target)

@@ -8,7 +8,9 @@ Morpher's automated test-vector flow.
 
 DFG sizes are chosen to be representative of the paper's kernels on a 4x4
 fabric (ResMII in the 2-4 range, so routing pressure — not raw FU count —
-decides II, which is what Table III measures).
+decides II, which is what Table III measures).  ``fft_strided`` is the
+exception: MachSuite's whole strided FFT at the suite's own size
+(``fft1024``, 5,120 iterations of one butterfly each).
 """
 from __future__ import annotations
 
@@ -80,6 +82,58 @@ def fft() -> KernelEntry:
     def mk(r):
         return {nm: _rand(r, N) for nm in ("ar", "ai", "br", "bi", "wr", "wi")}
     return b.build(), mk, N
+
+
+def fft_strided(n: int = 1024) -> KernelEntry:
+    """MachSuite ``fft/strided``: the whole radix-2 decimation-in-frequency
+    transform of ``n`` points, in place, output in bit-reversed order.
+
+    One iteration is one butterfly; the ``log2 n`` stages of ``n/2``
+    butterflies are flattened into ``(n/2)·log2 n`` iterations, the stage
+    ``t >> log2(n/2)`` and the butterfly ``t & (n/2 - 1)``.  Fixed point:
+    every stage halves its outputs and the twiddles ``wr``/``wi`` are Q8,
+    so the rotation is shifted down by 8.  Stage ``s + 1`` reads what
+    stage ``s`` stored; the nearest such store is ``n/4`` iterations back.
+    """
+    if n < 8 or n & (n - 1):
+        raise ValueError(f"fft_strided: n must be a power of two >= 8, "
+                         f"got {n}")
+    half = n // 2
+    lg = half.bit_length() - 1                   # log2(n/2)
+    b = DFGBuilder(f"fft{n}")
+    b.array("xr", n, output=True)
+    b.array("xi", n, output=True)
+    b.array("wr", half)
+    b.array("wi", half)
+    t = b.counter()
+    stage = b.op("SHR", t, lg)
+    bf = b.op("AND", t, half - 1)
+    span = b.op("SHR", half, stage)              # 1 << (lg - stage)
+    low = b.op("AND", bf, b.op("SUB", span, 1))
+    high = b.op("SUB", bf, low)                  # bf & ~(span - 1)
+    odd = b.op("OR", b.op("OR", b.op("SHL", high, 1), span), low)
+    even = b.op("XOR", odd, span)
+    root = b.op("AND", b.op("SHL", even, stage), n - 1)
+    xre, xro = b.load("xr", even), b.load("xr", odd)
+    xie, xio = b.load("xi", even), b.load("xi", odd)
+    wr, wi = b.load("wr", root), b.load("wi", root)
+    dr = b.op("SHR", b.op("SUB", xre, xro), 1)
+    di = b.op("SHR", b.op("SUB", xie, xio), 1)
+    b.store("xr", even, b.op("SHR", b.op("ADD", xre, xro), 1))
+    b.store("xi", even, b.op("SHR", b.op("ADD", xie, xio), 1))
+    b.store("xr", odd, b.op("SHR", b.op("SUB", b.op("MUL", wr, dr),
+                                        b.op("MUL", wi, di)), 8))
+    b.store("xi", odd, b.op("SHR", b.op("ADD", b.op("MUL", wr, di),
+                                        b.op("MUL", wi, dr)), 8))
+
+    angle = 2 * np.pi * np.arange(half) / n
+    twiddles = {"wr": np.round(256 * np.cos(angle)).astype(np.int32),
+                "wi": np.round(-256 * np.sin(angle)).astype(np.int32)}
+
+    def mk(r):
+        return {"xr": _rand(r, n, -32768, 32768),
+                "xi": _rand(r, n, -32768, 32768), **twiddles}
+    return b.build(), mk, half * (lg + 1)
 
 
 def adpcm() -> KernelEntry:
@@ -266,6 +320,7 @@ def jax_poly() -> KernelEntry:
 
 KERNELS: Dict[str, Callable[[], KernelEntry]] = {
     "fft": fft,
+    "fft1024": lambda: fft_strided(1024),
     "adpcm": adpcm,
     "aes": aes,
     "disparity": disparity,

@@ -93,6 +93,20 @@ class LinkedConfig:
     def total_cycles(self, n_iters: int) -> int:
         return self.t0_max + n_iters * self.II + self.II + 2
 
+    @property
+    def mem_slots(self) -> int:
+        """Scheduled LOAD/STORE slots: each fires once per iteration, and
+        each firing is one pass of ``cgra_exec`` over the scratchpad."""
+        opc, t0 = self.scalar[..., 0], self.scalar[..., 3]
+        mem = (opc == OPC["LOAD"]) | (opc == OPC["STORE"])
+        return int((mem & (t0 >= 0)).sum())
+
+
+def kernel_rounds(n_iters, II: int, t0_max: int):
+    """Rounds of the II slots ``cgra_exec`` runs for ``n_iters``:
+    ``ceil(total_cycles / II)``, where ``n_iters`` may be a traced scalar."""
+    return n_iters + 1 + (t0_max + 2 + II - 1) // II
+
 
 # Field layout of the Pallas kernel's tables (``kernel_tables``).
 # Per-PE vector table, one (P, KV_FIELDS) row block per slot:

@@ -54,7 +54,7 @@ from repro.core.lowering import (KC_HEAD, KC_MOVES, KC_OPS, KC_RES,
                                  KC_WIDTH, KS_CONST, KS_FIELDS, KS_HAS2,
                                  KS_HAS_IDX, KS_LIVE, KS_OPC, KS_Q0, KV_CONST,
                                  KV_LIVE, KV_OP, KV_OPC, KV_Q0, KV_T0OK,
-                                 LinkedConfig, kernel_tables)
+                                 LinkedConfig, kernel_rounds, kernel_tables)
 from repro.core.machine import OPC
 
 I32 = jnp.int32
@@ -170,8 +170,7 @@ def _cgra_kernel(niter_ref, stab_ref, vtab_ref, ctab_ref, mem_in_ref,
     N = P + P * R             # st_ref rows: [O; R] state, then the results
     M, B = mem_out_ref.shape
     n_iters = niter_ref[0, 0]           # traced: one trace, any trip count
-    # ceil(total_cycles / II) rounds of the II slots
-    n_rounds = n_iters + 1 + (t_max + 2 + II - 1) // II
+    n_rounds = kernel_rounds(n_iters, II, t_max)
     load, store = _mem_passes(mem_out_ref, chunk)
 
     def copy(c, carry):

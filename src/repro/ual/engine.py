@@ -39,7 +39,8 @@ of wall time the host spent working instead of blocked on the device),
 ``stream_chunks`` and throughput.
 
 Observability: every engine counts traces, calls, per-bucket hits,
-padding waste and streaming activity (``streams``/``stream_chunks``);
+padding waste, streaming activity (``streams``/``stream_chunks``) and
+the kernel's work (``fabric_cycles``/``mem_passes``, host arithmetic);
 ``CompiledKernelCache.stats()`` aggregates them (the execution service
 surfaces this in ``Service.stats()["engine"]``, and
 ``Executable.warmup()`` reports it in ``last_info``).
@@ -70,7 +71,7 @@ from typing import (Dict, Iterable, Iterator, List, Optional, Sequence,
 import numpy as np
 
 from repro import obs
-from repro.core.lowering import (LinkedConfig, kernel_tables,
+from repro.core.lowering import (LinkedConfig, kernel_rounds, kernel_tables,
                                  lowered_fingerprint, state_copy_counts)
 
 
@@ -109,6 +110,8 @@ class KernelEngine:
     """
 
     ENGINE_NAME = "pallas-jit"
+    #: kernel calls one block runs (one per device of the sharded engine)
+    n_devices = 1
 
     def _info_extra(self) -> Dict[str, object]:
         """Engine-flavor extras merged into per-call info and stats."""
@@ -147,6 +150,10 @@ class KernelEngine:
         self.padded_samples = 0
         self.streams = 0             # run_stream invocations completed
         self.stream_chunks = 0       # chunks drained across all streams
+        # the kernel's work, counted on the host per dispatched block
+        # (``_work``): fabric cycles of the real images, scratchpad passes
+        self.fabric_cycles = 0
+        self.mem_passes = 0
         self.bucket_calls: Dict[int, int] = {}
         self._warm: set = set()              # (M, bucket) already traced
         self._trace_lock = threading.Lock()
@@ -178,6 +185,16 @@ class KernelEngine:
         return call(niter, *self._tables, mem.T).T
 
     # -- execution ------------------------------------------------------------
+    def _work(self, n_iters: int, images: int, blocks: int
+              ) -> Tuple[int, int]:
+        """``(fabric_cycles, mem_passes)`` of ``blocks`` blocks carrying
+        ``images`` real images for ``n_iters``: every image runs the
+        kernel's rounds of II cycles, and every kernel call one pass over
+        its scratchpad block per fired LOAD/STORE slot."""
+        L = self.linked
+        cycles = kernel_rounds(n_iters, L.II, L.t0_max) * L.II * images
+        return cycles, L.mem_slots * n_iters * blocks * self.n_devices
+
     def bucket_for(self, b: int) -> int:
         """Smallest ladder bucket >= b (callers chunk at the largest)."""
         for bk in self.buckets:
@@ -259,6 +276,9 @@ class KernelEngine:
             self.padded_samples += sum(used) - B
             self.calls += 1
             self.samples += B
+            cycles, passes = self._work(n_iters, B, len(used))
+            self.fabric_cycles += cycles
+            self.mem_passes += passes
             traces_total = self.traces
         info = {
             "engine": self.ENGINE_NAME,
@@ -390,6 +410,9 @@ class KernelEngine:
             self.padded_samples += sum(used) - n_samples
             self.calls += 1
             self.samples += n_samples
+            cycles, passes = self._work(n_iters, n_samples, len(used))
+            self.fabric_cycles += cycles
+            self.mem_passes += passes
             self.streams += 1
             self.stream_chunks += n_chunks
             traces_total = self.traces
@@ -437,6 +460,8 @@ class KernelEngine:
                 "padded_samples": self.padded_samples,
                 "streams": self.streams,
                 "stream_chunks": self.stream_chunks,
+                "fabric_cycles": self.fabric_cycles,
+                "mem_passes": self.mem_passes,
                 "warm_shapes": sorted(self._warm),
             }
         calls = sum(bucket_calls.values())
@@ -651,6 +676,8 @@ class CompiledKernelCache:
             "padded_samples": sum(e["padded_samples"] for e in per.values()),
             "streams": sum(e["streams"] for e in per.values()),
             "stream_chunks": sum(e["stream_chunks"] for e in per.values()),
+            "fabric_cycles": sum(e["fabric_cycles"] for e in per.values()),
+            "mem_passes": sum(e["mem_passes"] for e in per.values()),
             "hit_ratio": round(hits / bucket_calls, 4) if bucket_calls
             else None,
             "per_engine": per,

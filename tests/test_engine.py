@@ -373,6 +373,32 @@ def test_engine_stats_report_state_copies(compiled):
     assert 0 < copied < dense
 
 
+def test_engine_counts_the_kernels_work(compiled):
+    """``fabric_cycles``: the kernel's rounds of II cycles (the whole
+    schedule, ``total_cycles``, in whole rounds) for every real image;
+    ``mem_passes``: one scratchpad pass per LOAD/STORE node, iteration
+    and kernel call.  ``run`` and ``run_stream`` both count, and the
+    cache sums its engines."""
+    from repro.core.lowering import kernel_rounds
+    program, exe = compiled
+    L = exe.lowered
+    cache = CompiledKernelCache()
+    eng = cache.engine_for(L)
+    M = program.layout.total_words
+    eng.run(np.zeros((5, M), np.int32), N_ITERS)               # 1 block
+    list(eng.run_stream(np.zeros((20, M), np.int32), N_ITERS,
+                        chunk=8))                              # 3 blocks
+    rounds = kernel_rounds(N_ITERS, L.II, L.t0_max)
+    assert rounds == -(-L.total_cycles(N_ITERS) // L.II)
+    assert L.mem_slots == program.dfg.n_mem_ops == 9
+    stats = eng.stats()
+    assert stats["fabric_cycles"] == rounds * L.II * (5 + 20)
+    assert stats["mem_passes"] == 9 * N_ITERS * 4
+    total = cache.stats()
+    assert (total["fabric_cycles"], total["mem_passes"]) == \
+        (stats["fabric_cycles"], stats["mem_passes"])
+
+
 @pytest.mark.parametrize("env_dir", [None, "given"])
 def test_compile_cache_placement(tmp_path, env_dir):
     """``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself);

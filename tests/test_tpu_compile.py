@@ -4,7 +4,7 @@ The TPU compiler is installed even where no chip is attached, and it
 compiles for a *described* chip.  These tests compile the kernel of the
 main path at the real width — the default scratchpad (M = 8192) and the
 engine's bucket sizes 8 and 128, on the published fabrics, and the
-benchmark's fft on PACE 8x8 at 128 lanes — for one chip
+benchmark's two FFTs on PACE 8x8 at 128 lanes — for one chip
 of a described ``v5e:2x2``, and check that the Mosaic kernel is in the
 compiled program.  Nothing
 runs, so they say nothing about results or times; the interpret-mode
@@ -58,10 +58,12 @@ FABRICS = {"hycube": dict(rows=4, cols=4), "n2n": dict(rows=4, cols=4),
            "pace": {}}
 
 
-#: gemm on every fabric at both widths, and the benchmark's fft on PACE
+#: gemm on every fabric at both widths, and the benchmark's two FFTs on
+#: PACE (a butterfly stage, and the whole 1,024-point transform)
 CASES = [pytest.param(fabric, bB, "gemm", id=f"{fabric}-{bB}")
          for fabric in sorted(FABRICS) for bB in (8, 128)] + [
-    pytest.param("pace", 128, "fft", id="pace-128-fft")]
+    pytest.param("pace", 128, k, id=f"pace-128-{k}")
+    for k in ("fft", "fft1024")]
 
 
 @pytest.mark.parametrize("fabric,bB,kernel_name", CASES)
