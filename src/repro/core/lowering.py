@@ -30,10 +30,11 @@ Lowered operand/source kinds (values in the dense tables):
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.core.dfg import DFG, DataLayout
 from repro.core.machine import (OPC, MachineConfig, SRC_CONST, SRC_NONE,
                                 SRC_REG, SRC_SELF, XB_IN, XB_NONE, XB_O,
                                 XB_REG)
@@ -42,8 +43,16 @@ K_NONE, K_O, K_R, K_CONST, K_RESULT = 0, 1, 2, 3, 4
 
 #: bump when the dense-table layout changes — folded into the on-disk
 #: cache entry name so stale lowered artifacts are never deserialized
-#: (v2: added the ``unresolved_inputs`` lowering-health counter)
-LOWERING_VERSION = 2
+#: (v2: added the ``unresolved_inputs`` lowering-health counter;
+#: v3: added the memory slots' scratchpad row ranges, ``mem_rows``)
+LOWERING_VERSION = 3
+
+#: the end of a row range that no layout bounds: past any scratchpad
+MEM_ROWS_END = int(np.iinfo(np.int32).max)
+
+#: rows of the scratchpad one compare/select pass of ``cgra_exec``
+#: touches at a time
+MEM_CHUNK = 512
 
 
 @dataclass
@@ -74,6 +83,15 @@ class LinkedConfig:
     #: count without re-deriving routing — this is the root exposure of
     #: the silent-``K_NONE`` lowering hazard
     unresolved_inputs: int = 0
+    #: (S, n_mem, 2) int32 ``[lo, hi)``: the scratchpad rows the LOAD or
+    #: STORE in each (slot, memory PE) may touch — its array's words under
+    #: the program's layout.  ``[0, MEM_ROWS_END)`` where no layout was at
+    #: hand (the default): the whole scratchpad
+    mem_rows: Optional[np.ndarray] = None
+
+    def __post_init__(self):
+        if self.mem_rows is None:
+            self.mem_rows = full_mem_rows(self.II, len(self.mem_pes))
 
     def cm_bytes(self) -> int:
         return self.scalar.nbytes + self.ops.nbytes + self.regw.nbytes
@@ -93,13 +111,72 @@ class LinkedConfig:
     def total_cycles(self, n_iters: int) -> int:
         return self.t0_max + n_iters * self.II + self.II + 2
 
+    def mem_slot_mask(self) -> np.ndarray:
+        """(S, n_mem) bool: the (slot, memory PE) pairs holding a scheduled
+        LOAD or STORE.  Each fires once per iteration, and each firing is
+        one pass of ``cgra_exec`` over its ``mem_rows``."""
+        sc = self.scalar[:, list(self.mem_pes)]
+        opc, t0 = sc[..., 0], sc[..., 3]
+        return ((opc == OPC["LOAD"]) | (opc == OPC["STORE"])) & (t0 >= 0)
+
     @property
     def mem_slots(self) -> int:
-        """Scheduled LOAD/STORE slots: each fires once per iteration, and
-        each firing is one pass of ``cgra_exec`` over the scratchpad."""
-        opc, t0 = self.scalar[..., 0], self.scalar[..., 3]
-        mem = (opc == OPC["LOAD"]) | (opc == OPC["STORE"])
-        return int((mem & (t0 >= 0)).sum())
+        """Scheduled LOAD/STORE slots (``mem_slot_mask``)."""
+        return int(self.mem_slot_mask().sum())
+
+
+def full_mem_rows(S: int, n_mem: int) -> np.ndarray:
+    """Row ranges that bound nothing: ``[0, MEM_ROWS_END)`` everywhere."""
+    rows = np.zeros((S, n_mem, 2), np.int32)
+    rows[..., 1] = MEM_ROWS_END
+    return rows
+
+
+def _mem_row_ranges(cfg: MachineConfig, dfg: DFG, layout: DataLayout
+                   ) -> np.ndarray:
+    """``[lo, hi)`` rows of each (slot, memory PE): the words of the array
+    its LOAD or STORE names, ``[base, base + length)`` under ``layout``;
+    the whole scratchpad for a slot with no such access.  ``dfg`` is the
+    mapped DFG (``cfg.node_id`` indexes its nodes)."""
+    mp = list(cfg.fabric.mem_pes)
+    rows = full_mem_rows(cfg.II, len(mp))
+    for s in range(cfg.II):
+        for j, p in enumerate(mp):
+            nid = int(cfg.node_id[s, p])
+            node = dfg.nodes[nid] if nid >= 0 else None
+            if (node is not None and node.is_mem
+                    and node.array in layout.bases):
+                lo = layout.bases[node.array]
+                rows[s, j] = lo, lo + dfg.arrays[node.array]
+    return rows
+
+
+def mem_chunk_rows(M: int) -> int:
+    """Rows of one pass step over an ``M``-row scratchpad: the largest
+    power of two (8 .. ``MEM_CHUNK``) dividing ``M``; ``M`` itself when
+    none does."""
+    c = MEM_CHUNK
+    while c >= 8:
+        if M % c == 0:
+            return c
+        c //= 2
+    return M
+
+
+def chunk_span(lo, hi, M: int, minimum=np.minimum):
+    """The chunks ``[c0, c1)`` of ``mem_chunk_rows(M)`` rows that cover
+    rows ``[lo, hi)``, clamped to the block's ``M // chunk`` chunks.
+
+    Shifts by the power-of-two chunk, no division, so the kernel calls it
+    on SMEM scalars (with ``jnp.minimum``) and the engine's counter on
+    numpy arrays.  A chunk that is no power of two is the whole block,
+    its one chunk."""
+    chunk = mem_chunk_rows(M)
+    n = M // chunk
+    if chunk & (chunk - 1):
+        return 0, n
+    k = chunk.bit_length() - 1
+    return minimum(lo >> k, n), minimum(((hi - 1) >> k) + 1, n)
 
 
 def kernel_rounds(n_iters, II: int, t0_max: int):
@@ -115,9 +192,11 @@ KV_OPC, KV_CONST, KV_T0OK, KV_LIVE, KV_Q0 = 0, 1, 2, 3, 4
 #: [is-immediate, dist, init, takes-trailing-immediate]
 KV_OP = 5
 KV_FIELDS = KV_OP + 12
-# Scalar (SMEM) table, KS_FIELDS words per (slot, memory PE):
+# Scalar (SMEM) table, KS_FIELDS words per (slot, memory PE); KS_LO and
+# KS_HI are the ``mem_rows`` range ``[lo, hi)`` its pass is bounded to
 KS_OPC, KS_CONST, KS_LIVE, KS_Q0, KS_HAS_IDX, KS_HAS2 = 0, 1, 2, 3, 4, 5
-KS_FIELDS = 6
+KS_LO, KS_HI = 6, 7
+KS_FIELDS = 8
 # Row-copy (SMEM) table: a KC_HEAD-word header per slot, [count, offset]
 # of each of its three lists, then the lists' entries.
 #: the lists: operand copies, register moves, result writes
@@ -143,7 +222,8 @@ def kernel_tables(linked: LinkedConfig
         iteration index a subtraction: with ``t = q*II + s``,
         ``(t - t0) // II == q - q0``.
       * ``stab`` (S * n_mem * KS_FIELDS,) int32: the memory PEs' fields,
-        flat for scalar memory.
+        flat for scalar memory, their row ranges (``mem_rows``) among
+        them.
       * ``ctab`` (flat int32, scalar memory): the rows each slot routes,
         as lists of row copies (``KC_*``).  Source rows index the stacked
         ``[O; R]`` state (``pe`` for K_O, ``P + pe*R + reg`` for K_R); an
@@ -196,6 +276,8 @@ def kernel_tables(linked: LinkedConfig
         stab[..., KS_Q0] = q0[:, mp]
         stab[..., KS_HAS_IDX] = ops[:, mp, 0, 0] != K_NONE
         stab[..., KS_HAS2] = ops[:, mp, 1, 0] != K_NONE
+        stab[..., KS_LO] = linked.mem_rows[..., 0]
+        stab[..., KS_HI] = linked.mem_rows[..., 1]
     # scalar memory cannot hold an empty array
     stab = stab.reshape(-1) if stab.size else np.zeros(1, np.int32)
 
@@ -236,6 +318,17 @@ def state_copy_counts(linked: LinkedConfig) -> Tuple[int, int]:
     return int(head[..., 0].sum()), S * (4 * n + P)
 
 
+def mem_chunk_counts(linked: LinkedConfig, M: int) -> Tuple[int, int]:
+    """``(bounded, dense)`` chunk steps of the scratchpad passes per
+    iteration over an ``M``-row block: each scheduled LOAD/STORE slot's
+    chunks that cover its ``mem_rows`` (``chunk_span``, as the kernel
+    computes them), and the ``M // chunk`` chunks of an unbounded pass."""
+    mem = linked.mem_slot_mask()
+    c0, c1 = chunk_span(linked.mem_rows[..., 0], linked.mem_rows[..., 1], M)
+    bounded = int(np.where(mem, c1 - c0, 0).sum())
+    return bounded, int(mem.sum()) * (M // mem_chunk_rows(M))
+
+
 def lowered_fingerprint(linked: LinkedConfig) -> str:
     """Content hash of the dense tables themselves.
 
@@ -253,7 +346,7 @@ def lowered_fingerprint(linked: LinkedConfig) -> str:
         h.update(f"{LOWERING_VERSION}:{linked.II}:{linked.n_pes}:"
                  f"{linked.n_regs}:{linked.mem_pes}:"
                  f"{linked.n_mem_ports}".encode())
-        for a in (linked.scalar, linked.ops, linked.regw):
+        for a in (linked.scalar, linked.ops, linked.regw, linked.mem_rows):
             h.update(np.ascontiguousarray(a).tobytes())
         fp = h.hexdigest()
         linked._fingerprint = fp
@@ -308,8 +401,13 @@ def _resolve_drivers(cfg: MachineConfig, s: int) -> np.ndarray:
     return drv
 
 
-def link_config(cfg: MachineConfig) -> LinkedConfig:
-    """Lower a MachineConfig to the dense tables the engines execute."""
+def link_config(cfg: MachineConfig, dfg: Optional[DFG] = None,
+                layout: Optional[DataLayout] = None) -> LinkedConfig:
+    """Lower a MachineConfig to the dense tables the engines execute.
+
+    Given the mapped ``dfg`` and its ``layout``, each memory slot's
+    scratchpad pass is bounded to its array's rows (``_mem_row_ranges``);
+    without them it spans the whole scratchpad."""
     S, P = cfg.II, cfg.fabric.n_pes
     R = cfg.regw.shape[2]
     scalar = np.zeros((S, P, 4), np.int32)
@@ -359,4 +457,6 @@ def link_config(cfg: MachineConfig) -> LinkedConfig:
                         mem_pes=tuple(cfg.fabric.mem_pes),
                         scalar=scalar, ops=ops, regw=regw,
                         n_mem_ports=cfg.fabric.n_mem_ports,
-                        unresolved_inputs=unresolved)
+                        unresolved_inputs=unresolved,
+                        mem_rows=(None if dfg is None or layout is None
+                                  else _mem_row_ranges(cfg, dfg, layout)))

@@ -19,7 +19,8 @@ so a 1:1 port would waste the TPU.  Instead:
   * the scratchpad lives in VMEM as an (M, lanes) block; LOAD/STORE are
     data-dependent per lane and become compare/select passes over it
     (TPU has no per-lane gather; this is the idiomatic replacement), run
-    only in cycles where the memory PE actually loads or stores.
+    only in cycles where the memory PE actually loads or stores, and only
+    over the rows of the array it accesses (``LinkedConfig.mem_rows``).
 
 Grid: (batch_tiles,) — each grid step simulates the whole fabric for one
 batch tile.  Cycle ``t = q*II + s``: nested ``fori_loop``s over the round
@@ -52,15 +53,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lowering import (KC_HEAD, KC_MOVES, KC_OPS, KC_RES,
                                  KC_WIDTH, KS_CONST, KS_FIELDS, KS_HAS2,
-                                 KS_HAS_IDX, KS_LIVE, KS_OPC, KS_Q0, KV_CONST,
-                                 KV_LIVE, KV_OP, KV_OPC, KV_Q0, KV_T0OK,
-                                 LinkedConfig, kernel_rounds, kernel_tables)
+                                 KS_HAS_IDX, KS_HI, KS_LIVE, KS_LO, KS_OPC,
+                                 KS_Q0, KV_CONST, KV_LIVE, KV_OP, KV_OPC,
+                                 KV_Q0, KV_T0OK, LinkedConfig, chunk_span,
+                                 kernel_rounds, kernel_tables,
+                                 mem_chunk_rows)
 from repro.core.machine import OPC
 
 I32 = jnp.int32
-
-#: rows of the scratchpad one compare/select pass touches at a time
-_MEM_CHUNK = 512
 
 
 def interpret_mode() -> bool:
@@ -136,29 +136,32 @@ def _alu(opc, v0, v1, v2, cvec):
 
 def _mem_passes(mem_ref, chunk: int):
     """``(load, store)`` over the (M, B) scratchpad ref, one ``chunk``-row
-    block at a time so a pass costs one block of vector registers."""
-    M, B = mem_ref.shape
-    n_chunks = M // chunk
+    block at a time so a pass costs one block of vector registers.  A pass
+    runs over the chunks ``[c0, c1)`` only (``chunk_span`` of the access's
+    ``mem_rows``): an address outside them loads 0 and stores nothing, as
+    one outside ``[0, M)`` always has.  The ``sim`` engines index the
+    scratchpad directly, so a program that reads or writes past its
+    declared array is ill-formed there already."""
+    B = mem_ref.shape[1]
 
     def rows(c):
         start = pl.multiple_of(c * chunk, chunk)
         return pl.ds(start, chunk), \
             jax.lax.broadcasted_iota(I32, (chunk, 1), 0) + start
 
-    def load(addr):                              # addr (1, B) -> (1, B)
+    def load(addr, c0, c1):                      # addr (1, B) -> (1, B)
         def body(c, acc):
             sl, idx = rows(c)
             return jnp.where(idx == addr, mem_ref[sl, :], acc)
-        acc = jax.lax.fori_loop(0, n_chunks, body,
-                                jnp.zeros((chunk, B), I32))
+        acc = jax.lax.fori_loop(c0, c1, body, jnp.zeros((chunk, B), I32))
         return jnp.sum(acc, axis=0, keepdims=True)
 
-    def store(addr, val):                        # (1, B) each
+    def store(addr, val, c0, c1):                # (1, B) each
         def body(c, carry):
             sl, idx = rows(c)
             mem_ref[sl, :] = jnp.where(idx == addr, val, mem_ref[sl, :])
             return carry
-        jax.lax.fori_loop(0, n_chunks, body, 0)
+        jax.lax.fori_loop(c0, c1, body, 0)
 
     return load, store
 
@@ -224,18 +227,22 @@ def _cgra_kernel(niter_ref, stab_ref, vtab_ref, ctab_ref, mem_in_ref,
                                stab_ref[base + KS_Q0], q)
             is_ld = m_fired & (m_opc == OPC["LOAD"])
             is_st = m_fired & (m_opc == OPC["STORE"])
+            c0, c1 = chunk_span(stab_ref[base + KS_LO],
+                                stab_ref[base + KS_HI], M, jnp.minimum)
             a0 = v0[mp:mp + 1, :]
             a1 = v1[mp:mp + 1, :]
             has_idx = _lanes(stab_ref[base + KS_HAS_IDX], B)
             has2 = _lanes(stab_ref[base + KS_HAS2], B)
             l_addr = jnp.where(has_idx, a0, 0) + m_const
-            lval = jax.lax.cond(is_ld, load, jnp.zeros_like, l_addr)
+            lval = jax.lax.cond(is_ld, load,
+                                lambda a, c0, c1: jnp.zeros_like(a),
+                                l_addr, c0, c1)
             s_addr = jnp.where(has2, a0 + m_const, m_const)
             s_val = jnp.where(has2, a1, a0)
 
             @pl.when(is_st)
             def _():
-                store(s_addr, s_val)
+                store(s_addr, s_val, c0, c1)
 
             row = jnp.where(_lanes(is_ld, B), lval,
                             jnp.where(_lanes(is_st, B), s_val,
@@ -272,17 +279,6 @@ def _cgra_kernel(niter_ref, stab_ref, vtab_ref, ctab_ref, mem_in_ref,
     jax.lax.fori_loop(0, n_rounds, round_, 0)
 
 
-def _mem_chunk(M: int) -> int:
-    """Largest power-of-two row block (8 .. ``_MEM_CHUNK``) dividing ``M``;
-    ``M`` itself when none does."""
-    c = _MEM_CHUNK
-    while c >= 8:
-        if M % c == 0:
-            return c
-        c //= 2
-    return M
-
-
 def _vmem_limit_bytes(M: int, bB: int) -> int:
     """Scoped-VMEM budget for one grid step: the in and out scratchpad
     blocks, double-buffered by the pipeline (lanes pad to 128), plus
@@ -315,7 +311,7 @@ def make_cgra_call(linked: LinkedConfig, *, M: int, bB: int,
     kernel = functools.partial(
         _cgra_kernel, II=linked.II, n_pes=linked.n_pes,
         n_regs=linked.n_regs, mem_pes=linked.mem_pes, t_max=linked.t0_max,
-        chunk=_mem_chunk(M))
+        chunk=mem_chunk_rows(M))
     _, vtab, _ = kernel_tables(linked)
     P, R = linked.n_pes, linked.n_regs
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)

@@ -16,9 +16,10 @@ arrays.  Three ship with the repo:
 ``sim`` and ``pallas`` both consume the shared **lowered artifact**
 (``core.lowering.LinkedConfig``) produced once by the compile pipeline's
 lowering pass: backends that set ``consumes_lowered = True`` receive it
-via the ``lowered`` keyword — the tables are program-independent (pure
-function of the machine configuration), so custom device backends can
-execute them directly instead of re-deriving routing from the raw config.
+via the ``lowered`` keyword — the tables are a pure function of the
+machine configuration and the program's layout (each memory slot's row
+range, ``mem_rows``), so custom device backends can execute them
+directly instead of re-deriving routing from the raw config.
 
 Third parties extend the layer with ``register_backend("mine", MyBackend())``
 — see ROADMAP.md for a worked example.  Backends are resolved by name at

@@ -40,7 +40,8 @@ of wall time the host spent working instead of blocked on the device),
 
 Observability: every engine counts traces, calls, per-bucket hits,
 padding waste, streaming activity (``streams``/``stream_chunks``) and
-the kernel's work (``fabric_cycles``/``mem_passes``, host arithmetic);
+the kernel's work (``fabric_cycles``/``mem_passes``/``mem_chunks``, host
+arithmetic);
 ``CompiledKernelCache.stats()`` aggregates them (the execution service
 surfaces this in ``Service.stats()["engine"]``, and
 ``Executable.warmup()`` reports it in ``last_info``).
@@ -72,7 +73,8 @@ import numpy as np
 
 from repro import obs
 from repro.core.lowering import (LinkedConfig, kernel_rounds, kernel_tables,
-                                 lowered_fingerprint, state_copy_counts)
+                                 lowered_fingerprint, mem_chunk_counts,
+                                 state_copy_counts)
 
 
 def make_cgra_call(*args, **kwargs):
@@ -151,9 +153,12 @@ class KernelEngine:
         self.streams = 0             # run_stream invocations completed
         self.stream_chunks = 0       # chunks drained across all streams
         # the kernel's work, counted on the host per dispatched block
-        # (``_work``): fabric cycles of the real images, scratchpad passes
+        # (``_count_work``): fabric cycles of the real images, scratchpad
+        # passes and the chunk steps those passes run
         self.fabric_cycles = 0
         self.mem_passes = 0
+        self.mem_chunks = 0
+        self._chunks_per_iter: Dict[int, int] = {}    # by M
         self.bucket_calls: Dict[int, int] = {}
         self._warm: set = set()              # (M, bucket) already traced
         self._trace_lock = threading.Lock()
@@ -185,15 +190,21 @@ class KernelEngine:
         return call(niter, *self._tables, mem.T).T
 
     # -- execution ------------------------------------------------------------
-    def _work(self, n_iters: int, images: int, blocks: int
-              ) -> Tuple[int, int]:
-        """``(fabric_cycles, mem_passes)`` of ``blocks`` blocks carrying
-        ``images`` real images for ``n_iters``: every image runs the
-        kernel's rounds of II cycles, and every kernel call one pass over
-        its scratchpad block per fired LOAD/STORE slot."""
+    def _count_work(self, n_iters: int, images: int, blocks: int,
+                    M: int) -> None:
+        """Add the work of ``blocks`` (·, ``M``) blocks carrying ``images``
+        real images for ``n_iters`` to the counters (under the stats
+        lock): every image runs the kernel's rounds of II cycles, and
+        every kernel call one pass per fired LOAD/STORE slot, over the
+        chunks of its row range (``mem_chunk_counts``)."""
         L = self.linked
-        cycles = kernel_rounds(n_iters, L.II, L.t0_max) * L.II * images
-        return cycles, L.mem_slots * n_iters * blocks * self.n_devices
+        calls = n_iters * blocks * self.n_devices
+        self.fabric_cycles += (kernel_rounds(n_iters, L.II, L.t0_max)
+                               * L.II * images)
+        self.mem_passes += L.mem_slots * calls
+        if M not in self._chunks_per_iter:
+            self._chunks_per_iter[M] = mem_chunk_counts(L, M)[0]
+        self.mem_chunks += self._chunks_per_iter[M] * calls
 
     def bucket_for(self, b: int) -> int:
         """Smallest ladder bucket >= b (callers chunk at the largest)."""
@@ -276,9 +287,7 @@ class KernelEngine:
             self.padded_samples += sum(used) - B
             self.calls += 1
             self.samples += B
-            cycles, passes = self._work(n_iters, B, len(used))
-            self.fabric_cycles += cycles
-            self.mem_passes += passes
+            self._count_work(n_iters, B, len(used), M)
             traces_total = self.traces
         info = {
             "engine": self.ENGINE_NAME,
@@ -362,6 +371,7 @@ class KernelEngine:
         n_samples = 0
         n_chunks = 0
         n_dispatched = 0
+        M = 0                      # the blocks' scratchpad rows
         tr = obs.tracer()
         tron = tr.enabled
         # one trace groups every chunk span of this stream in the export
@@ -386,7 +396,7 @@ class KernelEngine:
                          "samples": b, "traced": int(was_cold)}
 
         for blk in blocks():
-            b = blk.shape[0]
+            b, M = blk.shape
             with tr.span("stream:upload", "engine", trace=trace) as sp:
                 rows = self._block_rows(b)
                 if rows != b:
@@ -410,9 +420,7 @@ class KernelEngine:
             self.padded_samples += sum(used) - n_samples
             self.calls += 1
             self.samples += n_samples
-            cycles, passes = self._work(n_iters, n_samples, len(used))
-            self.fabric_cycles += cycles
-            self.mem_passes += passes
+            self._count_work(n_iters, n_samples, len(used), M)
             self.streams += 1
             self.stream_chunks += n_chunks
             traces_total = self.traces
@@ -462,6 +470,7 @@ class KernelEngine:
                 "stream_chunks": self.stream_chunks,
                 "fabric_cycles": self.fabric_cycles,
                 "mem_passes": self.mem_passes,
+                "mem_chunks": self.mem_chunks,
                 "warm_shapes": sorted(self._warm),
             }
         calls = sum(bucket_calls.values())
@@ -678,6 +687,7 @@ class CompiledKernelCache:
             "stream_chunks": sum(e["stream_chunks"] for e in per.values()),
             "fabric_cycles": sum(e["fabric_cycles"] for e in per.values()),
             "mem_passes": sum(e["mem_passes"] for e in per.values()),
+            "mem_chunks": sum(e["mem_chunks"] for e in per.values()),
             "hit_ratio": round(hits / bucket_calls, 4) if bucket_calls
             else None,
             "per_engine": per,

@@ -144,7 +144,9 @@ class Executable:
         if (self.lowered is None and self.map_result is not None
                 and self.map_result.config is not None):
             from repro.core.lowering import link_config
-            self.lowered = link_config(self.map_result.config)
+            self.lowered = link_config(self.map_result.config,
+                                       self.program.laid,
+                                       self.program.layout)
         return {"lowered": self.lowered}
 
     def _execute(self, mem: Dict[str, np.ndarray], n_iters: int,

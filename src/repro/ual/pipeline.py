@@ -220,8 +220,9 @@ class LoweringPass(CompilePass):
     The lowered artifact (``core.lowering.LinkedConfig``) is what every
     execution engine consumes — the vectorized batched simulator gathers
     over it, the Pallas kernel keeps it CM-resident in VMEM.  It is a
-    pure function of the machine configuration, so it is memoized in the
-    cache next to the ``MapResult`` under the same
+    pure function of the machine configuration and the program's layout
+    (which bounds each memory slot's scratchpad rows), so it is memoized
+    in the cache next to the ``MapResult`` under the same
     ``(program.digest, target.digest)`` key: a warm compile reuses the
     cached tables with zero re-lowering.  Skipped when there is nothing
     to lower (mapping-free backends, spatial fabrics, failed mappings).
@@ -240,8 +241,11 @@ class LoweringPass(CompilePass):
         # (re-map after a lost mapping pickle, racing processes sharing
         # the disk dir), and stale tables must read as a miss
         fp = config_fingerprint(r.config)
+
+        def lower(cfg):
+            return link_config(cfg, ctx.program.laid, ctx.program.layout)
         if not cacheable:
-            ctx.lowered = link_config(r.config)
+            ctx.lowered = lower(r.config)
             return {"cache": "bypass", "cm_bytes": ctx.lowered.cm_bytes()}
         c = ctx.cache if ctx.cache is not None else default_cache()
         if ctx.key_lock is not None:
@@ -249,7 +253,7 @@ class LoweringPass(CompilePass):
             # mapping pass, so nobody else can be lowering this key
             lowered = c.get_lowered(ctx.key, fp)
             if lowered is None:
-                lowered = link_config(r.config)
+                lowered = lower(r.config)
                 c.put_lowered(ctx.key, lowered, fp)
                 ctx.lowered = lowered
                 return {"cache": "miss", "cm_bytes": lowered.cm_bytes()}
@@ -268,7 +272,7 @@ class LoweringPass(CompilePass):
                 ctx.lowered = lowered
                 return {"cache": "hit", "inflight": True,
                         "cm_bytes": lowered.cm_bytes()}
-            lowered = link_config(r.config)
+            lowered = lower(r.config)
             c.put_lowered(ctx.key, lowered, fp)
         ctx.lowered = lowered
         return {"cache": "miss", "cm_bytes": lowered.cm_bytes()}

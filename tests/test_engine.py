@@ -399,6 +399,130 @@ def test_engine_counts_the_kernels_work(compiled):
         (stats["fabric_cycles"], stats["mem_passes"])
 
 
+# ---------------------------------------------------------------------------
+# scratchpad passes bounded to their arrays' rows
+# ---------------------------------------------------------------------------
+
+#: the benchmark's two short deployments at the default layout
+#: (M = 8192, 512-row chunks): (kernel, fabric, chunk steps per iteration
+#: bounded and dense)
+BOUNDED = {"gemm": ("hycube", dict(rows=4, cols=4), 9, 144),
+           "fft": ("pace", {}, 10, 160)}
+
+
+def _default_layout(kernel_name):
+    fabric, kw, _, _ = BOUNDED[kernel_name]
+    program = ual.Program.from_kernel(kernel_name)
+    exe = ual.compile(program, ual.Target.from_name(fabric, backend="pallas",
+                                                    **kw))
+    assert exe.success and program.layout.total_words == 8192
+    return program, exe
+
+
+@pytest.mark.parametrize("kernel_name", sorted(BOUNDED))
+def test_mem_rows_are_the_accessed_arrays(kernel_name):
+    """Each scheduled LOAD/STORE slot's row range is its node's array
+    under the program's layout, every other slot's the whole scratchpad;
+    the chunk steps per iteration are exact."""
+    from repro.core.lowering import MEM_ROWS_END, mem_chunk_counts
+    program, exe = _default_layout(kernel_name)
+    L, cfg, laid = exe.lowered, exe.map_result.config, program.laid
+    bases, arrays = program.layout.bases, program.dfg.arrays
+    mem_slots = 0
+    for s in range(L.II):
+        for j, p in enumerate(L.mem_pes):
+            nid = int(cfg.node_id[s, p])
+            node = laid.nodes[nid] if nid >= 0 else None
+            if node is not None and node.op in ("LOAD", "STORE"):
+                mem_slots += 1
+                lo = bases[node.array]
+                want = [lo, lo + arrays[node.array]]
+            else:
+                want = [0, MEM_ROWS_END]
+            assert L.mem_rows[s, j].tolist() == want, (s, p)
+    assert mem_slots == L.mem_slots
+    _, _, bounded, dense = BOUNDED[kernel_name]
+    assert mem_chunk_counts(L, 8192) == (bounded, dense)
+
+
+def test_no_layout_keeps_the_whole_scratchpad():
+    """``link_config(cfg)`` alone — the one-shot wrapper's and
+    ``cgra_exec_op``'s lowering — bounds nothing: every pass walks all
+    chunks, the tables hash apart from the bounded ones, and both give
+    the same images, bit-exact against the simulator."""
+    from repro.core.lowering import (MEM_ROWS_END, link_config,
+                                     lowered_fingerprint, mem_chunk_counts)
+    from repro.core.simulator import simulate_batch
+    program, exe = _default_layout("gemm")
+    full = link_config(exe.map_result.config)
+    assert (full.mem_rows[..., 0] == 0).all()
+    assert (full.mem_rows[..., 1] == MEM_ROWS_END).all()
+    assert mem_chunk_counts(full, 8192) == (144, 144)
+    assert lowered_fingerprint(full) != lowered_fingerprint(exe.lowered)
+    flats = program.flatten_batch(_mems(program, 3, seed=4))
+    cache = CompiledKernelCache()
+    got_full, _ = cache.run(full, flats, N_ITERS, lanes=8)
+    got_bounded, _ = cache.run(exe.lowered, flats, N_ITERS, lanes=8)
+    want, _ = simulate_batch(exe.lowered, flats, N_ITERS)
+    np.testing.assert_array_equal(got_full, want)
+    np.testing.assert_array_equal(got_bounded, want)
+
+
+def test_out_of_array_access_loads_zero_and_stores_nothing():
+    """The documented edge of the bounded passes: a LOAD past its array,
+    into another array's chunks, reads 0, and such a STORE writes
+    nothing, as an address outside ``[0, M)`` always did.  The simulator
+    indexes the flat scratchpad, so there the same program reads and
+    writes the neighbour: such a program is ill-formed."""
+    from repro.core.dfg import DFGBuilder
+    from repro.core.lowering import link_config
+    from repro.core.simulator import simulate_batch
+    n, bank = 4, 2048
+    b = DFGBuilder("past_the_array")
+    for name in ("A", "B", "C", "D"):
+        b.array(name, n)
+    i = b.counter()
+    past = b.op("ADD", i, const=bank)          # one bank past its array
+    b.store("C", i, b.load("A", past))         # reads B[i]
+    b.store("C", past, b.op("ADD", i, const=100))   # writes D[i]
+    program = ual.Program.from_builder(b, n, n_banks=4, bank_words=bank)
+    exe = ual.compile(program, ual.Target.from_name(
+        "hycube", rows=4, cols=4, backend="pallas"))
+    assert exe.success
+    rng = np.random.default_rng(2)
+    mem = {k: rng.integers(1, 50, n).astype(np.int32) for k in "ABCD"}
+    flats = program.flatten_batch([mem])
+    cache = CompiledKernelCache()
+    bounded = program.unflatten(cache.run(exe.lowered, flats, n)[0][0])
+    np.testing.assert_array_equal(bounded["C"], 0)
+    np.testing.assert_array_equal(bounded["D"], mem["D"])
+    # the whole-scratchpad tables and the simulator reach the neighbours
+    full = link_config(exe.map_result.config)
+    for out in (cache.run(full, flats, n)[0][0],
+                simulate_batch(exe.lowered, flats, n)[0][0]):
+        named = program.unflatten(out)
+        np.testing.assert_array_equal(named["C"], mem["B"])
+        np.testing.assert_array_equal(named["D"], np.arange(n) + 100)
+
+
+def test_engine_counts_the_chunk_steps():
+    """``mem_chunks``: per kernel call, each fired LOAD/STORE slot's chunk
+    span times ``n_iters`` — 9 chunk steps an iteration for gemm on
+    HyCUBE 4x4 at M = 8192, where unbounded passes would run 144.  One
+    streamed block and one ``run`` call; the cache sums its engines."""
+    program, exe = _default_layout("gemm")
+    cache = CompiledKernelCache()
+    eng = cache.engine_for(exe.lowered, lanes=8)
+    M = program.layout.total_words
+    list(eng.run_stream(np.zeros((8, M), np.int32), N_ITERS))   # 1 block
+    assert eng.stats()["mem_chunks"] == 9 * N_ITERS
+    eng.run(np.zeros((3, M), np.int32), 2)                      # 1 block
+    stats = eng.stats()
+    assert stats["mem_chunks"] == 9 * (N_ITERS + 2)
+    assert stats["mem_passes"] == stats["mem_chunks"]   # one chunk each
+    assert cache.stats()["mem_chunks"] == stats["mem_chunks"]
+
+
 @pytest.mark.parametrize("env_dir", [None, "given"])
 def test_compile_cache_placement(tmp_path, env_dir):
     """``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself);

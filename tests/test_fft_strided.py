@@ -108,6 +108,68 @@ def test_fft1024_compiles_clean_on_pace():
     assert lowered.t0_max // lowered.II + 1 <= 1024 // 4
 
 
+def _straddling_layout(n, how):
+    """A layout whose arrays cross pass chunk boundaries: 4 banks of 500
+    words (M 2,000, not a multiple of 512: 16-row chunks, which ``xi``
+    and ``wi`` cross), or bases set by hand so that every array crosses
+    a 512-row chunk boundary of M 4,096."""
+    from repro.core.dfg import DataLayout, plan_layout
+    dfg = fft_strided(n)[0]
+    if how == "bank_words=500":
+        return plan_layout(dfg, n_banks=4, bank_words=500)
+    ends = {"xr": 512, "xi": 1536, "wr": 2048, "wi": 3072}
+    return DataLayout({k: e - dfg.arrays[k] // 2 for k, e in ends.items()},
+                      {k: b for b, k in enumerate(ends)}, 4, 1024)
+
+
+@pytest.mark.parametrize("n,how", [(16, "bank_words=500"),
+                                   (64, "straddle-512")])
+def test_bounded_passes_cross_stages_bit_exact(n, how):
+    """The in-place read-modify-write crosses stages through the
+    scratchpad; with each pass bounded to the chunks its array covers
+    (and arrays that straddle a chunk boundary) the kernel stays
+    bit-exact against the reference and the simulator."""
+    from repro.core.lowering import mem_chunk_counts, mem_chunk_rows
+    dfg, mk, n_iters = fft_strided(n)
+    layout = _straddling_layout(n, how)
+    program = ual.Program(dfg, layout, n_iters, mk)
+    M = layout.total_words
+    chunk = mem_chunk_rows(M)
+    spans = {name: (lo + dfg.arrays[name] - 1) // chunk - lo // chunk + 1
+             for name, lo in layout.bases.items()}
+    assert spans["xi"] == spans["wi"] == 2
+    exe = ual.compile(program, ual.Target.from_name(
+        "pace", backend="pallas", max_hops=4))
+    assert exe.success and exe.check_report.ok
+    L = exe.lowered
+    assert L.t0_max // L.II + 1 <= n // 4
+    # xr and xi: two LOADs and two STOREs each; wr and wi: one LOAD each
+    bounded = 4 * (spans["xr"] + spans["xi"]) + spans["wr"] + spans["wi"]
+    assert mem_chunk_counts(L, M) == (bounded, 10 * (M // chunk))
+    rng = np.random.default_rng(n + 1)
+    mems = [program.random_inputs(rng) for _ in range(3)]
+    want = _reference(mems, n_iters)
+    for backend in ("pallas", "sim"):
+        outs = exe.run_batch(mems, n_iters, backend=backend)
+        for i, out in enumerate(outs):
+            for k in ("xr", "xi"):
+                assert np.array_equal(out[k], want[k][i]), (backend, k, i)
+
+
+def test_fft1024_passes_cover_18_of_160_chunks():
+    """At M = 8192 each of fft1024's 1,024-word arrays covers two of the
+    16 chunks of 512 rows and each twiddle array one: its 10 LOAD/STORE
+    slots (``xr`` and ``xi`` two loads and two stores each, ``wr`` and
+    ``wi`` one load) run 18 chunk steps an iteration instead of 160."""
+    from repro.core.lowering import mem_chunk_counts
+    program = ual.Program.from_kernel("fft1024")
+    exe = ual.compile(program, ual.Target.from_name("pace",
+                                                    backend="pallas"))
+    assert exe.success
+    assert mem_chunk_counts(exe.lowered, 8192) == (18, 160)
+    assert 18 * program.n_iters == 92_160
+
+
 def test_builder_refuses_a_size_that_is_not_a_power_of_two():
     for n in (4, 12, 1000):
         with pytest.raises(ValueError, match="power of two"):

@@ -148,7 +148,7 @@ def test_ssd_matches_model_chunked():
 # cgra_exec: bit-exact vs the cycle-accurate simulator (Morpher validation)
 # ---------------------------------------------------------------------------
 
-def _mapped(kernel_name, fabric):
+def _compiled(kernel_name, fabric):
     """Compile via the UAL so identical pairs are mapped once per session
     (the conftest installs a shared mapping cache)."""
     from repro import ual
@@ -156,6 +156,12 @@ def _mapped(kernel_name, fabric):
                                       n_banks=fabric.n_mem_ports)
     exe = ual.compile(program, ual.Target(fabric))
     assert exe.success, f"{kernel_name} failed to map on {fabric.name}"
+    return exe
+
+
+def _mapped(kernel_name, fabric):
+    exe = _compiled(kernel_name, fabric)
+    program = exe.program
     return exe.map_result, program.layout, program.make_mem, program.n_iters
 
 
@@ -193,6 +199,32 @@ def test_cgra_exec_bitexact_n2n(fabric, kernel_name):
     mems = np.stack([flat_memory(layout, mk(rng)) for _ in range(2)])
     got = cgra_exec_op(res.config, mems, n_iters)
     want = cgra_exec_ref(res.config, mems, n_iters)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("fabric,kernel_name", [
+    pytest.param("hycube", k, id=k)
+    for k in ("gemm", "fft", "adpcm", "aes", "disparity", "dct", "nw")] + [
+    pytest.param("pace", "fft", id="pace-fft")])
+def test_cgra_exec_bounded_passes_bitexact(fabric, kernel_name):
+    """The compile pipeline's tables bound every LOAD/STORE pass to its
+    array's chunks (``LinkedConfig.mem_rows``): bit-exact against the
+    cycle-accurate simulator on every kernel, at the default M = 8192."""
+    from repro.core.adl import hycube, pace
+    from repro.core.lowering import mem_chunk_counts
+    from repro.kernels.cgra_exec.ops import cgra_exec_op
+    from repro.kernels.cgra_exec.ref import cgra_exec_ref
+    fab = hycube(4, 4) if fabric == "hycube" else pace()
+    exe = _compiled(kernel_name, fab)
+    program = exe.program
+    M = program.layout.total_words
+    bounded, dense = mem_chunk_counts(exe.lowered, M)
+    assert 0 < bounded < dense
+    rng = np.random.default_rng(7)
+    mems = program.flatten_batch([program.make_mem(rng) for _ in range(3)])
+    got = cgra_exec_op(exe.map_result.config, mems, program.n_iters,
+                       linked=exe.lowered)
+    want = cgra_exec_ref(exe.map_result.config, mems, program.n_iters)
     np.testing.assert_array_equal(got, want)
 
 
