@@ -1,4 +1,4 @@
-"""Benchmark harness entry: one bench per paper table/figure + roofline.
+"""Benchmark harness entry: one bench per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--only NAME] [--smoke]
 
@@ -45,7 +45,7 @@ import time
 from benchmarks import (bench_chaos, bench_dse, bench_exec,
                         bench_fig9_spatial_vs_st,
                         bench_fig10_voltage, bench_fig11_breakdown,
-                        bench_roofline, bench_serve, bench_stream,
+                        bench_serve, bench_stream,
                         bench_table2_validation, bench_table3_multihop,
                         bench_table4_efficiency)
 from benchmarks.common import ART, fmt_table, save
@@ -57,7 +57,6 @@ BENCHES = {
     "table4_efficiency": bench_table4_efficiency.run,
     "fig10_voltage": bench_fig10_voltage.run,
     "fig11_breakdown": bench_fig11_breakdown.run,
-    "roofline": bench_roofline.run,
     "dse_explore": bench_dse.run,
     "exec_throughput": bench_exec.run,
     "serve_throughput": bench_serve.run,

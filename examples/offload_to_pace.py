@@ -3,7 +3,7 @@ jaxpr and map it onto the PACE 8x8 fabric.
 
 The paper's toolchain compiles *annotated kernels*; our frontend can also
 trace pure JAX scalar kernels (Morpher's LLVM-DFG analogue).  Here we take
-integer micro-kernels representative of the assigned LM architectures —
+integer micro-kernels of the kind LM inference runs —
 a quantized GQA score accumulation, an RWKV6-style decayed accumulate, and
 an int8 MoE router argmax step — trace them to DFGs, map at multiple hop
 budgets, and report II + estimated energy on the PACE model (edge

@@ -9,9 +9,7 @@ multiplexers are inferred from port fan-in.  This module provides
   * builders for the paper's fabrics: ``hycube`` (single-cycle multi-hop
     crossbar interconnect, multicast), ``n2n`` (neighbor-to-neighbor with
     FU route-through), ``pace`` (8x8, four clusters, 16-bit datapath) and a
-    ``spatial`` Snafu-like variant (no time multiplexing),
-  * a ``tpu_pod`` builder that describes a TPU mesh in the same vocabulary
-    (devices = PEs, ICI links = Connections) for the distributed scheduler.
+    ``spatial`` Snafu-like variant (no time multiplexing).
 
 Only scheduling-relevant semantics are modelled: FU opcode support, memory
 capability, per-PE input registers, directed links, the max number of link
@@ -121,7 +119,9 @@ class Fabric:
     cm_bytes_per_pe: int = 256             # configuration memory (PACE: 0.25KB)
     n_mem_ports: int = 4                   # shared scratchpad ports
     clusters: int = 1
-    link_gbps: float = 0.0                 # only for pod fabrics
+    # no built-in fabric sets link_gbps; it stays because to_json() feeds
+    # every Target.digest, and dropping it would re-key the mapping cache
+    link_gbps: float = 0.0
     attrs: Dict[str, object] = field(default_factory=dict)
 
     # -- derived -----------------------------------------------------------
@@ -204,21 +204,15 @@ class Fabric:
 # Builders
 # ---------------------------------------------------------------------------
 
-def _mesh_links(rows: int, cols: int, torus: bool = False) -> List[Tuple[int, int]]:
+def _mesh_links(rows: int, cols: int) -> List[Tuple[int, int]]:
     links = []
     for r in range(rows):
         for c in range(cols):
-            p = r * cols + c
             for dr, dc in ((0, 1), (0, -1), (1, 0), (-1, 0)):
                 rr, cc = r + dr, c + dc
-                if torus:
-                    rr, cc = rr % rows, cc % cols
-                elif not (0 <= rr < rows and 0 <= cc < cols):
-                    continue
-                q = rr * cols + cc
-                if q != p:
-                    links.append((p, q))
-    return sorted(set(links))
+                if 0 <= rr < rows and 0 <= cc < cols:
+                    links.append((r * cols + c, rr * cols + cc))
+    return sorted(links)
 
 
 def _pe_row(rows: int, cols: int, mem_cols: Sequence[int], ops: Sequence[str],
@@ -279,30 +273,9 @@ def spatial(rows: int = 4, cols: int = 4) -> Fabric:
     return f
 
 
-def tpu_pod(data: int = 16, model: int = 16, pods: int = 1,
-            link_gbps: float = 50.0) -> Fabric:
-    """A TPU pod in ADL vocabulary: chips = PEs, ICI = Connections.
-
-    Used by the pipeline scheduler and the roofline model; 2D ICI torus per
-    pod, pod axis connected by DCN-like links (modelled as regular links with
-    the same builder; bandwidth annotated).
-    """
-    rows, cols = data, model * pods
-    return Fabric(
-        name=f"tpu_pod_{pods}x{data}x{model}",
-        rows=rows, cols=cols,
-        pes=_pe_row(rows, cols, mem_cols=range(cols), ops=ALU_OPS, n_regs=2),
-        links=_mesh_links(rows, cols, torus=True),
-        max_hops=1, multicast=False, route_through_fu=False,
-        temporal=True, link_gbps=link_gbps,
-        attrs={"pods": pods, "data": data, "model": model},
-    )
-
-
 FABRIC_BUILDERS = {
     "hycube": hycube,
     "n2n": n2n,
     "pace": pace,
     "spatial": spatial,
-    "tpu_pod": tpu_pod,
 }

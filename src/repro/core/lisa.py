@@ -3,7 +3,7 @@
 LISA [HPCA'22] replaces simulated-annealing mapping with GNN-predicted
 labels that bias placement.  This is a deliberately small, fully
 self-contained analogue: an MLP scores (node, PE) pairs from structural
-features; it is trained — with this repo's own AdamW — on (node → chosen
+features; it is trained — with a small AdamW kept here — on (node → chosen
 PE) pairs harvested from successful low-II mappings of a training kernel
 set, and plugged into the mapper through the ``label_fn`` hook
 (`ModuloMapper(label_fn=...)`), biasing the PE ranking of the candidate
@@ -98,14 +98,46 @@ def collect_dataset(kernels: Sequence[Tuple[DFG, int]], fabric: Fabric,
     return np.stack(feats), np.array(labels, np.int32), pf
 
 
+# AdamW (betas 0.9/0.95, eps 1e-8, no weight decay) with global-norm
+# clipping at 1.0; the step size warms up linearly over 10 steps, then
+# decays on a cosine to a tenth of ``lr`` at the last step.
+_BETAS, _EPS, _CLIP, _WARMUP = (0.9, 0.95), 1e-8, 1.0, 10
+
+
+def _lr_at(lr: float, step, total_steps: int):
+    warm = jnp.minimum(step / _WARMUP, 1.0)
+    prog = jnp.clip((step - _WARMUP) / max(1, total_steps - _WARMUP),
+                    0.0, 1.0)
+    cos = 0.5 * (1 + jnp.cos(jnp.pi * prog))
+    return lr * warm * (0.1 + 0.9 * cos)
+
+
+def _adamw_step(params, grads, m, v, step, lr):
+    """One update at (1-based) ``step``; returns (params, m, v)."""
+    b1, b2 = _BETAS
+    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                         for g in jax.tree.leaves(grads)))
+    scale = jnp.minimum(1.0, _CLIP / (gnorm + 1e-9))
+
+    def upd(p, g, m, v):
+        g = g.astype(jnp.float32) * scale
+        m = b1 * m + (1 - b1) * g
+        v = b2 * v + (1 - b2) * jnp.square(g)
+        denom = jnp.sqrt(v / (1 - b2 ** step)) + _EPS
+        return p - lr * (m / (1 - b1 ** step) / denom), m, v
+
+    flat, tdef = jax.tree.flatten(params)
+    out = [upd(*a) for a in zip(flat, *(tdef.flatten_up_to(t)
+                                        for t in (grads, m, v)))]
+    return tuple(tdef.unflatten([o[i] for o in out]) for i in range(3))
+
+
 def train(feats: np.ndarray, labels: np.ndarray, pf: np.ndarray,
           steps: int = 300, lr: float = 1e-2, seed: int = 0):
-    """Softmax-over-PEs classification with this repo's AdamW."""
-    from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
+    """Softmax-over-PEs classification, trained with AdamW."""
     params = init_model(jax.random.PRNGKey(seed))
-    opt = OptConfig(lr=lr, warmup_steps=10, total_steps=steps,
-                    weight_decay=0.0)
-    state = init_opt_state(params, opt)
+    m = jax.tree.map(jnp.zeros_like, params)
+    v = jax.tree.map(jnp.zeros_like, params)
     X = jnp.asarray(feats)                        # (N, F)
     y = jnp.asarray(labels)                       # (N,)
     P = jnp.asarray(pf)                           # (n_pes, PF)
@@ -118,9 +150,11 @@ def train(feats: np.ndarray, labels: np.ndarray, pf: np.ndarray,
 
     loss_grad = jax.jit(jax.value_and_grad(loss_fn))
     losses = []
-    for _ in range(steps):
+    for step in range(1, steps + 1):
         loss, grads = loss_grad(params)
-        params, state, _ = adamw_update(params, grads, state, opt)
+        t = jnp.asarray(step, jnp.int32)
+        params, m, v = _adamw_step(params, grads, m, v, t,
+                                   _lr_at(lr, t, steps))
         losses.append(float(loss))
     return params, losses
 

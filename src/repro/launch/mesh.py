@@ -8,17 +8,12 @@ initializes its backends.  Once jax has picked up the flag, the CPU
 platform exposes N virtual devices — the mechanism the sharded serving
 cluster uses to test multi-device execution paths on a plain CPU host
 (see docs/serving.md for the recipe).
-
-The multi-pod production mesh used by the 512-device dry-run lives with
-its only consumer, ``repro.launch.dryrun`` (which sets the forced count
-to 512 at the top of its own module) — it is deliberately not part of
-this module's surface.
 """
 from __future__ import annotations
 
 import os
 import sys
-from typing import Mapping, Optional, Sequence
+from typing import Mapping, Optional
 
 _FLAG = "xla_force_host_platform_device_count"
 
@@ -73,11 +68,6 @@ def forced_device_env(n: int,
     env["XLA_FLAGS"] = _with_forced_count(env.get("XLA_FLAGS", ""), n)
     env["JAX_PLATFORMS"] = "cpu"
     return env
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    import jax
-    return jax.make_mesh(tuple(shape), tuple(axes))
 
 
 def make_host_mesh():

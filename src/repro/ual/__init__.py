@@ -59,7 +59,7 @@ Vocabulary:
 Extension points, all the same shape (named registry, duplicate names
 raise without ``overwrite=True``): ``register_backend``
 (interp/sim/pallas built-in), ``register_fabric``
-(hycube/n2n/pace/spatial/tpu_pod built-in) and ``register_strategy``
+(hycube/n2n/pace/spatial built-in) and ``register_strategy``
 (adaptive/sa built-in); enumerate with ``list_backends()`` /
 ``list_fabrics()`` / ``list_strategies()``.
 """

@@ -5,11 +5,9 @@ permanently shrank the pool.  This module is the parent-side policy
 state behind the healing watchdog: a ``RestartPolicy`` (how many
 respawns a worker slot gets, how long to back off between them) and a
 ``WorkerState`` per slot (deaths, restarts, due times, recovery
-timing).  It is the serving-side sibling of the training stack's
-checkpoint/restart supervisor (``repro.runtime.fault_tolerance``):
-same philosophy — bounded restarts, failures as recorded events — but
-for stateless pure-compute workers there is no checkpoint to restore;
-a respawned worker rejoins warm off the shared artifact cache.
+timing).  Restarts are bounded and every failure is a recorded event;
+the workers are stateless pure compute, so there is no checkpoint to
+restore: a respawned worker rejoins warm off the shared artifact cache.
 
 All mutation happens under the owning ``ClusterService``'s lock; this
 module holds no locks of its own.

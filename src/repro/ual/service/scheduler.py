@@ -76,6 +76,15 @@ class _StreamSpan:
 _IDLE_TICK_S = 0.05
 
 
+def _stream_summary(gen) -> Dict[str, object]:
+    """The summary a drained stream generator returns."""
+    try:
+        next(gen)
+    except StopIteration as stop:
+        return dict(stop.value or {})
+    raise RuntimeError("the stream yielded more samples than its span holds")
+
+
 class Service:
     """Dynamic-batching execution service over the UAL.
 
@@ -727,7 +736,9 @@ class Service:
         """Pipeline one stream span through the engine's double-buffered
         path, resolving each chunk's futures AS IT DRAINS — a consumer
         holding the ``StreamResponse`` sees chunk *i*'s results while
-        chunk *i+1* is still computing."""
+        chunk *i+1* is still computing.  The span is counted (service
+        stats, ``StreamResponse.info``) before its last chunk resolves,
+        so a client that has drained the stream reads every span."""
         live, exe = self._prepare(span.requests)
         if exe is None:
             return 0
@@ -737,16 +748,17 @@ class Service:
         gen = exe._execute_stream([req.mem for req in live],
                                   live[0].n_iters, None, chunk=span.chunk)
         try:
-            while True:
-                try:
-                    outs, cinfo = next(gen)
-                except StopIteration as stop:
-                    summary = dict(stop.value or {})
-                    break
+            while idx < len(live):
+                outs, cinfo = next(gen)
                 done = time.perf_counter()
                 members = live[idx:idx + len(outs)]
-                idx += len(outs)
                 n_chunks += 1
+                if idx + len(outs) == len(live):
+                    summary = _stream_summary(gen)
+                    self._metrics.record_stream_span(
+                        n_chunks, len(live), float(summary.get("wall_s", 0.0)),
+                        summary.get("overlap_frac"))
+                    span.stream._merge_span(summary)
                 for req, out in zip(members, outs):
                     latency = done - req.t_submit
                     self._metrics.record_completed(req.tenant, latency)
@@ -764,15 +776,12 @@ class Service:
                                           batch=len(outs), stream=True,
                                           chunk=cinfo.get("chunk"),
                                           **extra)
-        except Exception as exc:     # resolve the undrained tail
+                    idx += 1
+        except Exception as exc:     # resolve the unresolved tail
             self._metrics.record_error([req.tenant for req in live[idx:]])
             for req in live[idx:]:
                 req.response._resolve(exc=exc)
             return idx
-        self._metrics.record_stream_span(n_chunks, len(live),
-                                         float(summary.get("wall_s", 0.0)),
-                                         summary.get("overlap_frac"))
-        span.stream._merge_span(summary)
         return len(live)
 
     # -- observability --------------------------------------------------------

@@ -3,7 +3,7 @@
 A Target names a fabric (the elaborated ADL topology), a mapper strategy
 with its quality knobs, and an execution backend.  Fabrics come from a
 registry keyed by the ADL builder names (``hycube``/``n2n``/``pace``/
-``spatial``/``tpu_pod``); backends come from the pluggable registry in
+``spatial``); backends come from the pluggable registry in
 ``ual.backends``.
 
 ``Target.digest`` hashes only what the *mapper* consumes — the fabric
@@ -32,7 +32,7 @@ def register_fabric(name: str, builder: Callable[..., Fabric],
     replacement is how two plugins stomp each other.  ``builder`` is any
     callable returning a ``Fabric`` (``Target.from_name`` forwards its
     non-knob keyword arguments to it); the ADL builders
-    ``hycube``/``n2n``/``pace``/``spatial``/``tpu_pod`` ship built-in.
+    ``hycube``/``n2n``/``pace``/``spatial`` ship built-in.
     """
     if name in FABRICS and not overwrite:
         raise ValueError(f"fabric {name!r} already registered; "

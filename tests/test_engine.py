@@ -25,6 +25,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from deployments import DEPLOYMENTS, FABRICS
 from repro import ual
 from repro.core.dfg import interpret
 from repro.core.simulator import simulate_reference
@@ -281,13 +282,7 @@ def test_kernel_tables_iteration_index_is_a_subtraction(II):
                 assert q - q0 == (t - t0) // II, (II, t0, s, q)
 
 
-#: the published fabrics: HyCUBE 4x4, N2N 4x4 and PACE 8x8
-FABRICS = {"hycube": dict(rows=4, cols=4), "n2n": dict(rows=4, cols=4),
-           "pace": {}}
-
-
-@pytest.mark.parametrize("fabric", sorted(FABRICS))
-@pytest.mark.parametrize("kernel_name", ["gemm", "fft"])
+@pytest.mark.parametrize("kernel_name,fabric", DEPLOYMENTS)
 def test_copy_lists_match_dense_gather(kernel_name, fabric):
     """The kernel's row-copy lists (``ctab``) move exactly what a dense
     one-hot gather over the whole PE state selects: every operand (an

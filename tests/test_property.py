@@ -166,73 +166,3 @@ def test_pallas_kernel_matches_simulator(dfg):
     got = cgra_exec_op(res.config, mems, 6)
     want = cgra_exec_ref(res.config, mems, 6)
     np.testing.assert_array_equal(got, want)
-
-
-@settings(max_examples=20, deadline=None)
-@given(st.integers(2, 8), st.integers(1, 16), st.integers(1, 3))
-def test_pipeline_schedules_always_valid(S, M, C):
-    from repro.core.pipeline_schedule import (gpipe, interleaved_1f1b,
-                                              one_f_one_b)
-    gpipe(S, M).verify()
-    one_f_one_b(S, M).verify()
-    interleaved_1f1b(S, M, n_chunks=C).verify()
-
-
-@settings(max_examples=10, deadline=None)
-@given(st.lists(st.integers(1, 6), min_size=1, max_size=4),
-       st.integers(0, 100))
-def test_checkpoint_roundtrip_property(dims, seed):
-    import tempfile
-    import jax.numpy as jnp
-    from repro.checkpoint.checkpoint import restore, save
-    rng = np.random.default_rng(seed)
-    tree = {"a": {"w": jnp.asarray(rng.normal(size=tuple(dims)),
-                                   jnp.float32)},
-            "b": [jnp.asarray(rng.integers(0, 9, (3,)), jnp.int32),
-                  jnp.float32(seed)]}
-    with tempfile.TemporaryDirectory() as d:
-        save(d, 1, tree)
-        got, manifest = restore(d, tree)
-        assert manifest["step"] == 1
-        for x, y in zip(np.asarray(got["a"]["w"]).ravel(),
-                        np.asarray(tree["a"]["w"]).ravel()):
-            assert x == y
-        np.testing.assert_array_equal(got["b"][0], tree["b"][0])
-
-
-@settings(max_examples=10, deadline=None)
-@given(st.integers(1, 5), st.integers(0, 50))
-def test_data_pipeline_host_invariance(n_hosts, step):
-    """Global batch content is invariant to how many hosts shard it."""
-    from repro.configs import smoke_config
-    from repro.data.pipeline import DataConfig, host_batch
-    cfg = smoke_config("qwen3-8b")
-    dc = DataConfig(global_batch=np.lcm.reduce([n_hosts, 2]) * 2, seq_len=8)
-    if dc.global_batch % n_hosts:
-        return
-    full = host_batch(cfg, dc, step, 0, 1)["tokens"]
-    parts = [host_batch(cfg, dc, step, h, n_hosts)["tokens"]
-             for h in range(n_hosts)]
-    np.testing.assert_array_equal(full, np.concatenate(parts))
-
-
-@settings(max_examples=8, deadline=None)
-@given(st.integers(1, 4), st.integers(1, 4), st.booleans())
-def test_opt_state_specs_match_state_structure(rows, cols, factored):
-    """Spec tree structure must match init_opt_state exactly (the arctic
-    dry-run bug class), for any mix of 1-D and 2-D params."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from repro.train.optimizer import (OptConfig, init_opt_state,
-                                       opt_state_specs)
-    opt = OptConfig(factored=factored)
-    params = {"w": jnp.zeros((rows * 8, cols * 8)), "norm": jnp.zeros((8,))}
-    specs = {"w": P(None, None), "norm": P(None)}
-    state = init_opt_state(params, opt)
-    sspecs = opt_state_specs(specs, opt, params)
-    t1 = jax.tree_util.tree_structure(state)
-    t2 = jax.tree_util.tree_structure(
-        jax.tree.map(lambda s: 0, sspecs,
-                     is_leaf=lambda x: isinstance(x, P)))
-    assert t1 == t2

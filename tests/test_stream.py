@@ -4,7 +4,8 @@ The contract under test:
 
   * streamed chunks are bit-exact vs the discrete ``run_batch`` path and
     the unpadded DFG-interpreter oracle — including a ragged final chunk
-    and the chunk == 1 degenerate,
+    and the chunk == 1 degenerate — and ``run_stream`` matches the oracle
+    on every published (kernel, fabric) deployment,
   * streaming on a warm engine adds ZERO traces, and cold streaming
     traffic stays O(#buckets) (monkeypatch-counted on the shared
     ``make_cgra_call`` constructor, PR-5 pattern),
@@ -22,6 +23,7 @@ The contract under test:
 import numpy as np
 import pytest
 
+from deployments import DEPLOYMENTS, FABRICS
 from repro import ual
 from repro.core.dfg import interpret
 from repro.ual.engine import CompiledKernelCache
@@ -77,6 +79,26 @@ def test_stream_bitexact_vs_run_batch_and_oracle(compiled, B, chunk):
             np.testing.assert_array_equal(got[name], want[name])
             np.testing.assert_array_equal(got[name], oracle[name])
     assert summary["stream_chunks"] == len(chunks)
+
+
+@pytest.mark.parametrize("kernel_name,fabric", DEPLOYMENTS)
+def test_run_stream_matches_oracle_every_deployment(kernel_name, fabric):
+    """``Executable.run_stream``, the path the chip benchmark times, on
+    every published deployment at the default scratchpad: three seeded
+    images at chunk=2 through the pallas engine, every output array
+    bit-exact against the DFG interpreter."""
+    program = ual.Program.from_kernel(kernel_name)
+    exe = ual.compile(program, ual.Target.from_name(
+        fabric, backend="pallas", **FABRICS[fabric]))
+    assert exe.success
+    mems = _mems(program, 3, seed=31)
+    chunks, summary = _drain(exe.run_stream(mems, chunk=2))
+    assert [len(c) for c in chunks] == [2, 1]
+    assert summary["stream_chunks"] == 2
+    for m, got in zip(mems, (d for c in chunks for d in c)):
+        want = interpret(program.dfg, m, program.n_iters)
+        for name in program.outputs:
+            np.testing.assert_array_equal(got[name], want[name])
 
 
 def test_run_batch_stream_flag_collects_and_reports(compiled):
@@ -246,6 +268,33 @@ def test_submit_stream_interleaves_with_discrete_tenants(compiled):
     assert sr.responses[0].info.get("stream") is True
     # discrete traffic still coalesced normally alongside the stream
     assert stats["completed"] == 80
+
+
+def test_stream_span_counted_before_its_last_chunk_resolves(compiled,
+                                                           monkeypatch):
+    """Every span is merged into ``StreamResponse.info`` (and counted in
+    the service's stats) while its last chunk is still pending, so a
+    client that has drained the stream reads every span, whatever the
+    threads' timing."""
+    from repro.ual.service.queue import StreamResponse
+    program, _exe = compiled
+    target = ual.Target.from_name("hycube", rows=4, cols=4,
+                                  backend="pallas")
+    all_done_at_merge = []
+    merge = StreamResponse._merge_span
+
+    def spy(self, summary):
+        all_done_at_merge.append(self.done())
+        merge(self, summary)
+
+    monkeypatch.setattr(StreamResponse, "_merge_span", spy)
+    with ual.Service(max_batch=16, max_wait_ms=2.0) as svc:
+        sr = svc.submit_stream(program, target, _mems(program, 20, seed=23),
+                               n_iters=N_ITERS, chunk=4, span=2)
+        sr.results(timeout=300)
+        assert sr.info["spans"] == 3 and sr.info["samples"] == 20
+        assert svc.stats()["stream"]["spans"] == 3
+    assert all_done_at_merge == [False, False, False]
 
 
 def test_submit_stream_queue_full_is_all_or_nothing(compiled):

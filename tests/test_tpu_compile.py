@@ -19,6 +19,7 @@ import os
 
 import pytest
 
+from deployments import FABRICS
 from repro import ual
 
 M = 8192
@@ -51,11 +52,6 @@ def no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
-
-
-#: the published fabrics: HyCUBE 4x4, N2N 4x4 and PACE 8x8
-FABRICS = {"hycube": dict(rows=4, cols=4), "n2n": dict(rows=4, cols=4),
-           "pace": {}}
 
 
 #: gemm on every fabric at both widths, and the benchmark's two FFTs on

@@ -1,7 +1,7 @@
 """Compile-time config verifier: differential corruption fuzzing.
 
 Strategy: compile real kernels to real configs (which must verify
-CLEAN on every registered temporal fabric), then inject one corruption
+CLEAN on every published deployment), then inject one corruption
 class at a time into a cloned config and assert the verifier reports
 exactly the expected diagnostic code.  The injections mirror the hazard
 classes the engines would otherwise only hit at runtime — or never
@@ -12,6 +12,7 @@ from dataclasses import replace
 import numpy as np
 import pytest
 
+from deployments import DEPLOYMENTS, FABRICS
 from repro import ual
 from repro.analysis.verifier import (CODES, CheckReport, Diagnostic,
                                      VerifyError, raise_if_errors, verify)
@@ -21,9 +22,6 @@ from repro.core.machine import (OPC, SRC_IN, SRC_REG, XB_IN, XB_NONE, XB_O,
                                 MachineConfig)
 from repro.core.simulator import BatchedSimulator
 
-TEMPORAL_FABRICS = (("hycube", dict(rows=4, cols=4)),
-                    ("n2n", dict(rows=4, cols=4)),
-                    ("pace", {}))
 
 
 def _compiled(kernel, fab_name, kwargs):
@@ -57,13 +55,11 @@ def gemm_hycube():
     return _compiled("gemm", "hycube", dict(rows=4, cols=4))
 
 
-# -- clean configs: zero findings on every registered temporal fabric -------
+# -- clean configs: zero findings on every published deployment -------------
 
-@pytest.mark.parametrize("kernel", ["gemm", "fft"])
-@pytest.mark.parametrize("fab_name,kwargs", TEMPORAL_FABRICS,
-                         ids=[f[0] for f in TEMPORAL_FABRICS])
-def test_clean_configs_verify_clean(kernel, fab_name, kwargs):
-    program, target, exe = _compiled(kernel, fab_name, kwargs)
+@pytest.mark.parametrize("kernel,fab_name", DEPLOYMENTS)
+def test_clean_configs_verify_clean(kernel, fab_name):
+    program, target, exe = _compiled(kernel, fab_name, FABRICS[fab_name])
     rep = verify(cfg=exe.map_result.config, linked=exe.lowered,
                  program=program)
     assert rep.diagnostics == [], rep.render()
