@@ -502,18 +502,59 @@ def unflatten_memory(layout: DataLayout, flat: np.ndarray,
             for name, ln in arrays.items()}
 
 
+#: ``(base, length)`` runs of scratchpad words, ascending and disjoint
+Runs = Tuple[Tuple[int, int], ...]
+
+
+def live_runs(layout: DataLayout, arrays: Dict[str, int]) -> Runs:
+    """The scratchpad words the declared ``arrays`` occupy under
+    ``layout``, as ascending ``(base, length)`` runs with adjacent arrays
+    merged.  ``plan_layout`` packs each bank from its start, so there
+    are at most ``n_banks`` runs."""
+    runs: List[List[int]] = []
+    for base, ln in sorted((layout.bases[n], ln) for n, ln in arrays.items()
+                           if ln > 0):
+        if runs and runs[-1][0] + runs[-1][1] >= base:
+            runs[-1][1] = max(runs[-1][1], base + ln - runs[-1][0])
+        else:
+            runs.append([base, ln])
+    return tuple((b, n) for b, n in runs)
+
+
+def _columns(layout: DataLayout, runs: Optional[Runs]
+             ) -> Tuple[Dict[str, int], int]:
+    """Each array's first column in a block that holds only the words of
+    ``runs`` side by side (all of the scratchpad when ``runs`` is None),
+    and the block's width.  An empty array outside every run moves no
+    word, from column 0."""
+    if runs is None:
+        return layout.bases, layout.total_words
+    starts, width = [], 0
+    for lo, n in runs:
+        starts.append((lo, n, width))
+        width += n
+    cols = {name: next((at + base - lo for lo, n, at in starts
+                        if lo <= base < lo + n), 0)
+            for name, base in layout.bases.items()}
+    return cols, width
+
+
 def flat_memory_batch(layout: DataLayout,
-                      mems: List[Dict[str, np.ndarray]]) -> np.ndarray:
-    """Batched ``flat_memory``: B named-array dicts -> (B, total_words).
+                      mems: List[Dict[str, np.ndarray]],
+                      runs: Optional[Runs] = None) -> np.ndarray:
+    """Batched ``flat_memory``: B named-array dicts -> (B, total_words),
+    or with ``runs`` (``live_runs``) -> (B, L): only those words, run
+    after run, L their total.
 
     One allocation and one vectorized assignment per *array name* instead
     of a Python loop over samples — the hot path of every natively-batched
     backend.  Samples may still omit arrays (zero-filled) or pass short
     arrays; only such ragged names fall back to a per-sample copy.
     """
+    cols, width = _columns(layout, runs)
     B = len(mems)
-    flat = np.zeros((B, layout.total_words), INT)
-    for name, base in layout.bases.items():
+    flat = np.zeros((B, width), INT)
+    for name, base in cols.items():
         rows = [m.get(name) for m in mems]
         present = [r for r in rows if r is not None]
         if not present:
@@ -530,14 +571,17 @@ def flat_memory_batch(layout: DataLayout,
 
 
 def unflatten_memory_batch(layout: DataLayout, flats: np.ndarray,
-                           arrays: Dict[str, int]
+                           arrays: Dict[str, int],
+                           runs: Optional[Runs] = None
                            ) -> List[Dict[str, np.ndarray]]:
-    """Batched ``unflatten_memory``: (B, total_words) -> B dicts.
+    """Batched ``unflatten_memory``: (B, total_words) -> B dicts, or
+    (B, L) -> B dicts for a block of ``runs`` (``flat_memory_batch``).
 
     One contiguous copy per array name; the per-sample dicts share those
     copies as row views (callers treat outputs as read-only snapshots,
     exactly like the scalar path's fresh arrays)."""
-    cols = {name: flats[:, layout.bases[name]:layout.bases[name] + ln].copy()
+    at, _ = _columns(layout, runs)
+    cols = {name: flats[:, at[name]:at[name] + ln].copy()
             for name, ln in arrays.items()}
     return [{name: col[b] for name, col in cols.items()}
             for b in range(flats.shape[0])]

@@ -11,7 +11,8 @@ arrays.  Three ship with the repo:
     tables (batched; compiled on a TPU, interpreted on any other
     platform) through the persistent JIT
     engine (``repro.ual.engine``): trace-once/run-many with batch-bucket
-    padding, tables device-resident per engine, ``n_iters`` traced.
+    padding, tables device-resident per engine, ``n_iters`` traced, and
+    only the program's declared arrays moved between host and device.
 
 ``sim`` and ``pallas`` both consume the shared **lowered artifact**
 (``core.lowering.LinkedConfig``) produced once by the compile pipeline's
@@ -170,7 +171,11 @@ class PallasBackend(Backend):
     through the persistent JIT engine (``repro.ual.engine``): the linked
     tables live on device per engine, ``n_iters`` is traced, and batch
     sizes are padded up the bucket ladder so repeat traffic hits warm
-    traces — trace once, run many.
+    traces — trace once, run many.  Blocks carry only the words of the
+    program's declared arrays (``Program.live``, packed by
+    ``Program.pack_batch``): the engine scatters them into the zeroed
+    (M, bucket) scratchpad on the device and gathers them back, so host
+    and device exchange L words an image, not the scratchpad's M.
 
     Two multi-device modes (the serving cluster's substrates):
 
@@ -219,28 +224,31 @@ class PallasBackend(Backend):
 
     def execute_batch(self, program, result, mems, n_iters, lowered=None,
                       device=None, flats=None):
-        if flats is None:
-            flats = program.flatten_batch(mems)
+        block = (program.pack_batch(mems) if flats is None
+                 else program.pack_flats(flats))
+        packing = dict(live=program.live, M=program.layout.total_words)
         linked = _ensure_lowered(result, lowered)
         if self.sharded:
-            out, info = self.engine.sharded_run(linked, flats, n_iters,
-                                                lanes=self.lanes)
+            out, info = self.engine.sharded_run(linked, block, n_iters,
+                                                lanes=self.lanes, **packing)
         else:
-            out, info = self.engine.run(linked, flats, n_iters,
-                                        lanes=self.lanes, device=device)
+            out, info = self.engine.run(linked, block, n_iters,
+                                        lanes=self.lanes, device=device,
+                                        **packing)
         info["batched"] = True
-        return program.unflatten_batch(out), info
+        return program.unpack_batch(out), info
 
     def execute_stream(self, program, result, mems, n_iters, *,
                        chunk=None, lowered=None, device=None):
         """Genuinely pipelined streaming: chunks flow through the
-        persistent engine's double-buffered ``run_stream`` — while chunk
-        *i* computes on device, the host flattens/uploads chunk *i+1*
-        and unflattens chunk *i-1*'s drained rows.  Same bucket-ladder
-        traces as ``execute_batch``; the summary carries the engine's
-        measured ``overlap_frac``.  With the tracer on, each block adds
-        ``stream:flatten`` and ``stream:unflatten`` spans to the engine's
-        stream trace."""
+        persistent engine's double-buffered ``run_stream`` as packed
+        (b, L) blocks of the program's live words — while chunk *i*
+        computes on device, the host packs/uploads chunk *i+1* and
+        unpacks chunk *i-1*'s drained rows.  Same bucket-ladder traces
+        as ``execute_batch``; the summary carries the engine's measured
+        ``overlap_frac``.  With the tracer on, each block adds
+        ``stream:flatten`` (the pack) and ``stream:unflatten`` (the
+        unpack) spans to the engine's stream trace."""
         linked = _ensure_lowered(result, lowered)
         eng = self._engine_for(linked, device=device)
         step = (max(1, min(int(chunk), eng._capacity())) if chunk
@@ -250,7 +258,7 @@ class PallasBackend(Backend):
 
         def flatten(group):
             with tr.span("stream:flatten", "engine", trace=trace):
-                return program.flatten_batch(group)
+                return program.pack_batch(group)
 
         def blocks():
             group = []
@@ -262,7 +270,9 @@ class PallasBackend(Backend):
             if group:
                 yield flatten(group)
 
-        gen = eng.run_stream(blocks(), n_iters, chunk=step, trace=trace)
+        gen = eng.run_stream(blocks(), n_iters, chunk=step, trace=trace,
+                             live=program.live,
+                             M=program.layout.total_words)
         while True:
             try:
                 out, cinfo = next(gen)
@@ -271,17 +281,18 @@ class PallasBackend(Backend):
                 summary["batched"] = True
                 return summary
             with tr.span("stream:unflatten", "engine", trace=trace):
-                outs = program.unflatten_batch(out)
+                outs = program.unpack_batch(out)
             yield outs, cinfo
 
     def warmup(self, program, result, lowered=None, buckets=None,
                device=None):
-        """Pre-trace the bucket ladder for this program's scratchpad width
+        """Pre-trace the bucket ladder for this program's packed blocks
         (``n_iters`` is traced, so one trace per bucket covers every trip
         count).  Returns the engine's stats."""
         linked = _ensure_lowered(result, lowered)
         eng = self._engine_for(linked, device=device)
-        return eng.warmup(program.layout.total_words, buckets)
+        return eng.warmup(program.layout.total_words, buckets,
+                          live=program.live)
 
 
 # ---------------------------------------------------------------------------

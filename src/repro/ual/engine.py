@@ -26,6 +26,17 @@ times.  This module is that half of the story:
     batches beyond the largest bucket run as warm largest-bucket chunks —
     the trace count stays O(#buckets) no matter how traffic is shaped.
 
+Packed blocks: a program's declared arrays cover a small part of its
+scratchpad (129 of 8,192 words for gemm), so the host moves only those
+words.  ``run``/``run_stream`` take ``live=`` (``Program.live``: the
+``(base, length)`` runs of the arrays' words) and ``M=``: each image is
+then a row of L = sum of the run lengths words.  Inside the one jit the
+block is scattered into a zeroed (M, bucket) scratchpad — what the
+kernel works on, unchanged — and the same rows are gathered back out
+after it, so upload and copy-back carry L words an image, not M.
+Without ``live`` a block is a (bucket, M) row of whole images, for
+direct callers that hold them.
+
 Streaming (the STRELA mode — data flows through a resident config):
 ``run`` is upload -> sweep -> download in strict sequence, so on large
 batches the host<->device transfer time is dead time.  ``run_stream``
@@ -39,9 +50,9 @@ of wall time the host spent working instead of blocked on the device),
 ``stream_chunks`` and throughput.
 
 Observability: every engine counts traces, calls, per-bucket hits,
-padding waste, streaming activity (``streams``/``stream_chunks``) and
-the kernel's work (``fabric_cycles``/``mem_passes``/``mem_chunks``, host
-arithmetic);
+padding waste, streaming activity (``streams``/``stream_chunks``), the
+words its blocks move (``transfer_words``) and the kernel's work
+(``fabric_cycles``/``mem_passes``/``mem_chunks``, host arithmetic);
 ``CompiledKernelCache.stats()`` aggregates them (the execution service
 surfaces this in ``Service.stats()["engine"]``, and
 ``Executable.warmup()`` reports it in ``last_info``).
@@ -72,6 +83,7 @@ from typing import (Dict, Iterable, Iterator, List, Optional, Sequence,
 import numpy as np
 
 from repro import obs
+from repro.core.dfg import Runs
 from repro.core.lowering import (LinkedConfig, kernel_rounds, kernel_tables,
                                  lowered_fingerprint, mem_chunk_counts,
                                  state_copy_counts)
@@ -84,6 +96,55 @@ def make_cgra_call(*args, **kwargs):
     threads), while tests can still monkeypatch-count traces here."""
     from repro.kernels.cgra_exec.kernel import make_cgra_call as real
     return real(*args, **kwargs)
+
+
+def _scatter(block, live: Runs, M: int):
+    """A packed (rows, L) ``block`` -> (rows, M) whole images (traced):
+    its columns, run after run, land on the words ``live`` names, and
+    every other word is zero."""
+    import jax.numpy as jnp
+    mem = jnp.zeros((block.shape[0], M), jnp.int32)
+    col = 0
+    for lo, n in live:
+        # each run zero-padded to the whole image and summed: one fused
+        # pass on the TPU, where a concatenate of several runs lowers to
+        # in-place updates of a buffer from an ``AllocateBuffer`` custom
+        # call, a second custom call beside the kernel's in the program
+        mem = mem + jnp.pad(block[:, col:col + n], ((0, 0), (lo, M - lo - n)))
+        col += n
+    return mem
+
+
+def _gather(mem, live: Runs):
+    """(rows, M) whole images -> the (rows, L) block of their ``live``
+    words (traced)."""
+    import jax.numpy as jnp
+    return jnp.concatenate([mem[:, lo:lo + n] for lo, n in live]
+                           + [mem[:, :0]], axis=1)
+
+
+def _packing(width: int, live: Optional[Sequence[Tuple[int, int]]],
+             M: Optional[int]) -> Tuple[Runs, int]:
+    """``(live, M)`` for blocks ``width`` words an image wide: without
+    ``live`` a block is whole images (its own scratchpad); with it the
+    runs must be ascending, disjoint, inside ``[0, M)`` and ``width``
+    words in all."""
+    if live is None:
+        if M not in (None, width):
+            raise ValueError(f"a full-width block is {width} words an "
+                             f"image, not M={M}")
+        return ((0, width),), width
+    live = tuple((int(lo), int(n)) for lo, n in live)
+    end = 0
+    for lo, n in live:
+        if lo < end or n < 1:
+            raise ValueError(f"live runs {live} are not ascending and "
+                             f"disjoint")
+        end = lo + n
+    if M is None or end > M or sum(n for _, n in live) != width:
+        raise ValueError(f"live runs {live} do not fit a {width}-word "
+                         f"block of an M={M} scratchpad")
+    return live, int(M)
 
 
 def bucket_ladder(lanes: int = 128,
@@ -104,7 +165,8 @@ class KernelEngine:
 
     Owns the device-resident tables (uploaded once, closed over as jit
     constants) and the single jitted entry point; ``jax.jit`` specializes
-    it per ``(M, bucket)`` shape, and the ladder keeps that set small.
+    it per ``(M, bucket, live)`` (the live runs and M are static), and
+    the ladder keeps that set small.
 
     ``device=`` pins the engine (tables AND per-call operands) to one
     device — the replica path: N pinned engines on N host devices
@@ -152,6 +214,9 @@ class KernelEngine:
         self.padded_samples = 0
         self.streams = 0             # run_stream invocations completed
         self.stream_chunks = 0       # chunks drained across all streams
+        # int32 words blocks moved host->device plus device->host,
+        # padding rows included
+        self.transfer_words = 0
         # the kernel's work, counted on the host per dispatched block
         # (``_count_work``): fabric cycles of the real images, scratchpad
         # passes and the chunk steps those passes run
@@ -160,10 +225,10 @@ class KernelEngine:
         self.mem_chunks = 0
         self._chunks_per_iter: Dict[int, int] = {}    # by M
         self.bucket_calls: Dict[int, int] = {}
-        self._warm: set = set()              # (M, bucket) already traced
+        self._warm: set = set()        # (M, bucket, live) already traced
         self._trace_lock = threading.Lock()
         self._stats_lock = threading.Lock()
-        self._fn = jax.jit(self._traced)
+        self._fn = jax.jit(self._packed, static_argnames=("live", "M"))
 
     # -- placement (overridden by the sharded engine) -------------------------
     def _put_tables(self, linked: LinkedConfig) -> tuple:
@@ -181,9 +246,17 @@ class KernelEngine:
             return self._jnp.asarray(arr)
         return self._jax.device_put(self._jnp.asarray(arr), self.device)
 
-    # -- the traced function --------------------------------------------------
+    # -- the traced functions -------------------------------------------------
+    def _packed(self, niter, block, live: Runs, M: int):
+        """The jitted entry: ``block`` is one padded (bucket, L) block of
+        the ``live`` words of M-word images.  It becomes zero-filled whole
+        images on the device, runs through ``_traced`` and comes back as
+        the same words; retraced per ``(M, bucket, live)``."""
+        return _gather(self._traced(niter, _scatter(block, live, M)), live)
+
     def _traced(self, niter, mem):
-        """``mem`` is one padded (bucket, M) block; retraced per shape."""
+        """``mem`` is one padded (bucket, M) block of whole images: the
+        kernel runs on its transpose, the (M, bucket) scratchpad."""
         self.traces += 1
         bucket, M = mem.shape
         call = make_cgra_call(self.linked, M=M, bB=bucket, n_tiles=1)
@@ -224,36 +297,45 @@ class KernelEngine:
         ``n_devices * bucket_for(ceil(chunk / n_devices))``."""
         return self.bucket_for(chunk)
 
-    def _call_block(self, block: np.ndarray, niter
-                    ) -> Tuple[np.ndarray, bool]:
-        """One padded (bucket, M) block through the jitted entry point;
-        cold ``(M, bucket)`` shapes trace under the trace lock so
-        concurrent workers pay exactly one trace per bucket.  Returns
-        ``(out, was_cold)`` — cold means THIS call found the shape
-        untraced (info attribution stays per-call under concurrency)."""
-        key = (block.shape[1], block.shape[0])
+    def _dispatch_block(self, block: np.ndarray, niter, live: Runs, M: int
+                        ) -> Tuple[object, bool]:
+        """Asynchronously dispatch one padded block; returns the device
+        future WITHOUT materializing it, and whether THIS call found the
+        shape untraced (info attribution stays per-call under
+        concurrency).  Warm shapes return immediately (jax async
+        dispatch); cold ``(M, bucket, live)`` shapes trace synchronously
+        under the trace lock, so concurrent workers pay exactly one trace
+        per bucket — a cold trace takes seconds and must not sit in the
+        pipeline as if it were a 1 ms hop."""
+        key = (M, block.shape[0], live)
         with self._stats_lock:
             warm = key in self._warm
         if warm:
-            return np.asarray(self._fn(niter, self._put_operand(block))), \
-                False
+            return self._fn(niter, self._put_operand(block), live=live,
+                            M=M), False
         with self._trace_lock:
-            out = np.asarray(self._fn(niter, self._put_operand(block)))
+            fut = self._fn(niter, self._put_operand(block), live=live, M=M)
+            fut.block_until_ready()
             with self._stats_lock:
                 self._warm.add(key)
-        return out, True
+        return fut, True
 
-    def run(self, flats: np.ndarray, n_iters: int
+    def run(self, flats: np.ndarray, n_iters: int, *,
+            live: Optional[Sequence[Tuple[int, int]]] = None,
+            M: Optional[int] = None
             ) -> Tuple[np.ndarray, Dict[str, object]]:
-        """Execute a (B, M) batch of scratchpad images for ``n_iters``.
+        """Execute a batch for ``n_iters``: (B, M) whole scratchpad
+        images, or with ``live`` and ``M`` a (B, L) packed block of the
+        ``live`` words (``Program.pack_batch``).
 
         Pads each chunk up the bucket ladder (B > largest bucket runs as
         warm largest-bucket chunks) and slices the padding back off;
-        returns ``(out (B, M), per-call info)``.
+        returns ``(out, per-call info)``, ``out`` shaped like ``flats``.
         """
         jnp = self._jnp
         flats = np.ascontiguousarray(flats, np.int32)
-        B, M = flats.shape
+        B, width = flats.shape
+        live, M = _packing(width, live, M)
         niter = self._put_operand(
             jnp.asarray(n_iters, jnp.int32).reshape(1, 1))
         used: List[int] = []
@@ -262,11 +344,12 @@ class KernelEngine:
         if B <= top and self._block_rows(B) == B:
             # pad-free fast path: the batch IS a bucket — no padding
             # rows to append, no staging buffer to copy through
-            out, was_cold = self._call_block(flats, niter)
+            fut, was_cold = self._dispatch_block(flats, niter, live, M)
+            out = np.asarray(fut)
             cold_blocks = int(was_cold)
             used.append(B)
         else:
-            out = np.empty((B, M), np.int32)
+            out = np.empty((B, width), np.int32)
             i = 0
             while i < B:
                 chunk = min(B - i, top)
@@ -274,9 +357,9 @@ class KernelEngine:
                 block = flats[i:i + chunk]
                 if rows != chunk:
                     block = np.concatenate(
-                        [block, np.zeros((rows - chunk, M), np.int32)])
-                block_out, was_cold = self._call_block(block, niter)
-                out[i:i + chunk] = block_out[:chunk]
+                        [block, np.zeros((rows - chunk, width), np.int32)])
+                fut, was_cold = self._dispatch_block(block, niter, live, M)
+                out[i:i + chunk] = np.asarray(fut)[:chunk]
                 cold_blocks += was_cold
                 used.append(rows)
                 i += chunk
@@ -287,6 +370,7 @@ class KernelEngine:
             self.padded_samples += sum(used) - B
             self.calls += 1
             self.samples += B
+            self.transfer_words += 2 * width * sum(used)
             self._count_work(n_iters, B, len(used), M)
             traces_total = self.traces
         info = {
@@ -300,35 +384,20 @@ class KernelEngine:
         return out, info
 
     # -- streaming ------------------------------------------------------------
-    def _dispatch_block(self, block: np.ndarray, niter
-                        ) -> Tuple[object, bool]:
-        """Asynchronously dispatch one padded block; returns the device
-        future WITHOUT materializing it.  Warm shapes return immediately
-        (jax async dispatch); cold shapes trace synchronously under the
-        trace lock — a cold trace takes seconds and must not sit in the
-        pipeline as if it were a 1 ms hop."""
-        key = (block.shape[1], block.shape[0])
-        with self._stats_lock:
-            warm = key in self._warm
-        if warm:
-            return self._fn(niter, self._put_operand(block)), False
-        with self._trace_lock:
-            fut = self._fn(niter, self._put_operand(block))
-            fut.block_until_ready()
-            with self._stats_lock:
-                self._warm.add(key)
-        return fut, True
-
     def run_stream(self, source: Union[np.ndarray, Iterable[np.ndarray]],
                    n_iters: int, *, chunk: Optional[int] = None,
-                   depth: int = 2, trace: Optional[str] = None
+                   depth: int = 2, trace: Optional[str] = None,
+                   live: Optional[Sequence[Tuple[int, int]]] = None,
+                   M: Optional[int] = None
                    ) -> Iterator[Tuple[np.ndarray, Dict[str, object]]]:
         """Streaming execution: pipeline warm-bucket chunks with double
-        buffering, yielding ``(out_chunk (b, M), chunk_info)`` as each
-        chunk drains.
+        buffering, yielding ``(out_chunk, chunk_info)`` as each chunk
+        drains, ``out_chunk`` (b, W) like its input rows.
 
-        ``source`` is a (B, M) batch or an iterable of (b, M) row blocks
-        (blocks larger than ``chunk`` are re-chunked).  While chunk *i*
+        ``source`` is a (B, W) batch or an iterable of (b, W) row blocks
+        (blocks larger than ``chunk`` are re-chunked): whole (W = M)
+        scratchpad images, or with ``live`` and ``M`` packed blocks of
+        the ``live`` words (W = L, as ``run``).  While chunk *i*
         computes on device, the host pads/uploads chunk *i+1* and
         converts chunk *i-1*'s results — jax async dispatch keeps up to
         ``depth`` chunks in flight, so host<->device transfer work
@@ -371,7 +440,8 @@ class KernelEngine:
         n_samples = 0
         n_chunks = 0
         n_dispatched = 0
-        M = 0                      # the blocks' scratchpad rows
+        M_run = 0                  # the blocks' scratchpad rows
+        words = 0                  # int32 words moved, both ways
         tr = obs.tracer()
         tron = tr.enabled
         # one trace groups every chunk span of this stream in the export
@@ -396,17 +466,19 @@ class KernelEngine:
                          "samples": b, "traced": int(was_cold)}
 
         for blk in blocks():
-            b, M = blk.shape
+            b, width = blk.shape
+            lv, M_run = _packing(width, live, M)
             with tr.span("stream:upload", "engine", trace=trace) as sp:
                 rows = self._block_rows(b)
                 if rows != b:
                     blk = np.concatenate(
-                        [blk, np.zeros((rows - b, blk.shape[1]), np.int32)])
-                fut, was_cold = self._dispatch_block(blk, niter)
+                        [blk, np.zeros((rows - b, width), np.int32)])
+                fut, was_cold = self._dispatch_block(blk, niter, lv, M_run)
                 if tron:
                     sp.set(chunk=n_dispatched, bucket=rows, samples=b,
                            traced=int(was_cold))
             inflight.append((fut, b, rows, was_cold))
+            words += 2 * width * rows
             n_dispatched += 1
             while len(inflight) > depth:
                 yield drain()
@@ -420,7 +492,8 @@ class KernelEngine:
             self.padded_samples += sum(used) - n_samples
             self.calls += 1
             self.samples += n_samples
-            self._count_work(n_iters, n_samples, len(used), M)
+            self.transfer_words += words
+            self._count_work(n_iters, n_samples, len(used), M_run)
             self.streams += 1
             self.stream_chunks += n_chunks
             traces_total = self.traces
@@ -441,20 +514,26 @@ class KernelEngine:
         }
 
     def warmup(self, M: int,
-               buckets: Optional[Sequence[int]] = None) -> Dict[str, object]:
+               buckets: Optional[Sequence[int]] = None, *,
+               live: Optional[Sequence[Tuple[int, int]]] = None
+               ) -> Dict[str, object]:
         """Pre-trace the ladder (or a subset) for scratchpad width ``M``
-        with a zero batch — ``n_iters`` is traced, so one warm trace per
-        bucket covers every trip count.  Requested sizes off the engine's
-        ladder snap UP to the bucket that will actually execute them
+        — whole images, or packed blocks of the ``live`` words — with a
+        zero batch: ``n_iters`` is traced, so one warm trace per bucket
+        covers every trip count.  Requested sizes off the engine's ladder
+        snap UP to the bucket that will actually execute them
         (``bucket_for``), so re-warming is always a no-op.  Returns this
         engine's stats."""
+        width = M if live is None else sum(int(n) for _, n in live)
+        live, M = _packing(width, live, M)
         want = sorted({self._block_rows(min(b, self._capacity())) for b in
                        bucket_ladder(self.lanes, buckets or self.buckets)})
         for rows in want:
             with self._stats_lock:
-                warm = (M, rows) in self._warm
+                warm = (M, rows, live) in self._warm
             if not warm:
-                self.run(np.zeros((rows, M), np.int32), 1)
+                self.run(np.zeros((rows, width), np.int32), 1, live=live,
+                         M=M)
         return self.stats()
 
     # -- observability --------------------------------------------------------
@@ -468,6 +547,7 @@ class KernelEngine:
                 "padded_samples": self.padded_samples,
                 "streams": self.streams,
                 "stream_chunks": self.stream_chunks,
+                "transfer_words": self.transfer_words,
                 "fabric_cycles": self.fabric_cycles,
                 "mem_passes": self.mem_passes,
                 "mem_chunks": self.mem_chunks,
@@ -550,9 +630,11 @@ class ShardedKernelEngine(KernelEngine):
         return self._jnp.asarray(arr)
 
     def _traced(self, niter, mem):
-        """``mem`` is one (n_devices * bucket, M) global block; each
-        device's shard runs the same pallas_call at the per-device
-        bucket shape — one trace, every device."""
+        """``mem`` is one (n_devices * bucket, M) global block of whole
+        images (the packed entry around it is the single-device
+        engine's, partitioned along the batch axis); each device's shard
+        runs the same pallas_call at the per-device bucket shape — one
+        trace, every device."""
         from jax.sharding import PartitionSpec as P
         jax = self._jax
         self.traces += 1
@@ -635,32 +717,34 @@ class CompiledKernelCache:
             return eng
 
     def run(self, linked: LinkedConfig, flats: np.ndarray, n_iters: int, *,
-            lanes: int = 128, device=None
+            lanes: int = 128, device=None, live=None, M=None
             ) -> Tuple[np.ndarray, Dict[str, object]]:
         eng = self.engine_for(linked, lanes=lanes, device=device)
-        return eng.run(flats, n_iters)
+        return eng.run(flats, n_iters, live=live, M=M)
 
     def sharded_run(self, linked: LinkedConfig, flats: np.ndarray,
-                    n_iters: int, *, lanes: int = 128, mesh=None
+                    n_iters: int, *, lanes: int = 128, mesh=None,
+                    live=None, M=None
                     ) -> Tuple[np.ndarray, Dict[str, object]]:
         eng = self.sharded_engine_for(linked, lanes=lanes, mesh=mesh)
-        return eng.run(flats, n_iters)
+        return eng.run(flats, n_iters, live=live, M=M)
 
     def run_stream(self, linked: LinkedConfig, source, n_iters: int, *,
                    chunk: Optional[int] = None, depth: int = 2,
-                   lanes: int = 128, device=None
+                   lanes: int = 128, device=None, live=None, M=None
                    ) -> Iterator[Tuple[np.ndarray, Dict[str, object]]]:
         """Streaming execution through the cached engine for ``linked``
         (see ``KernelEngine.run_stream``); yields drained chunks, returns
         the stream summary via ``StopIteration.value``."""
         eng = self.engine_for(linked, lanes=lanes, device=device)
-        return eng.run_stream(source, n_iters, chunk=chunk, depth=depth)
+        return eng.run_stream(source, n_iters, chunk=chunk, depth=depth,
+                              live=live, M=M)
 
     def warmup(self, linked: LinkedConfig, M: int, *,
                buckets: Optional[Sequence[int]] = None, lanes: int = 128,
-               device=None) -> Dict[str, object]:
+               device=None, live=None) -> Dict[str, object]:
         eng = self.engine_for(linked, lanes=lanes, device=device)
-        return eng.warmup(M, buckets)
+        return eng.warmup(M, buckets, live=live)
 
     def stats(self) -> Dict[str, object]:
         """Aggregate over every engine: total traces / calls / samples,
@@ -685,6 +769,8 @@ class CompiledKernelCache:
             "padded_samples": sum(e["padded_samples"] for e in per.values()),
             "streams": sum(e["streams"] for e in per.values()),
             "stream_chunks": sum(e["stream_chunks"] for e in per.values()),
+            "transfer_words": sum(e["transfer_words"]
+                                  for e in per.values()),
             "fabric_cycles": sum(e["fabric_cycles"] for e in per.values()),
             "mem_passes": sum(e["mem_passes"] for e in per.values()),
             "mem_chunks": sum(e["mem_chunks"] for e in per.values()),

@@ -25,9 +25,9 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.dfg import (DFG, DataLayout, DFGBuilder, apply_layout,
-                            flat_memory, flat_memory_batch, plan_layout,
-                            trace_into, unflatten_memory,
+from repro.core.dfg import (DFG, DataLayout, DFGBuilder, Runs, apply_layout,
+                            flat_memory, flat_memory_batch, live_runs,
+                            plan_layout, trace_into, unflatten_memory,
                             unflatten_memory_batch)
 
 
@@ -101,6 +101,37 @@ class Program:
         """Batched ``unflatten``: (B, total_words) -> B named-array dicts
         (one contiguous copy per array name)."""
         return unflatten_memory_batch(self.layout, flats, self.dfg.arrays)
+
+    # -- the live words: what moves between host and device -------------------
+    @cached_property
+    def live(self) -> Runs:
+        """The scratchpad words the declared arrays occupy, as ascending
+        ``(base, length)`` runs (``core.dfg.live_runs``): all that a
+        packed block carries.  Every other word starts at zero and is
+        never read back."""
+        return live_runs(self.layout, self.dfg.arrays)
+
+    def pack_batch(self, mems: Sequence[Dict[str, np.ndarray]]
+                   ) -> np.ndarray:
+        """B dicts -> (B, L) packed block: the ``live`` words alone, run
+        after run, one vectorized pass per array name (missing arrays
+        zeroed) — what the pallas backend uploads."""
+        mems = list(mems)
+        for m in mems:
+            self.check_arrays(m)
+        return flat_memory_batch(self.layout, mems, self.live)
+
+    def pack_flats(self, flats: np.ndarray) -> np.ndarray:
+        """(B, total_words) images -> their (B, L) packed block."""
+        cols = np.concatenate([np.arange(lo, lo + n) for lo, n in self.live]
+                              + [np.zeros(0, np.int64)])
+        return np.asarray(flats, np.int32)[:, cols]
+
+    def unpack_batch(self, block: np.ndarray
+                     ) -> "list[Dict[str, np.ndarray]]":
+        """(B, L) packed block -> B named-array dicts."""
+        return unflatten_memory_batch(self.layout, block, self.dfg.arrays,
+                                      self.live)
 
     def random_inputs(self, rng: np.random.Generator,
                       lo: int = -50, hi: int = 50) -> Dict[str, np.ndarray]:

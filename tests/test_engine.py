@@ -15,7 +15,12 @@ The contract under test:
     same configuration twice (the fingerprint memo),
   * ``Program.flatten_batch``/``unflatten_batch`` match the per-sample
     scalar paths exactly (including missing / short arrays),
-  * ``Service.stats()`` surfaces the engine aggregate.
+  * ``Service.stats()`` surfaces the engine aggregate,
+  * packed blocks: the pallas backend moves only the program's declared
+    arrays (``Program.live``), and every packed entry — batch with and
+    without ``flats=``, stream, the sharded engine — matches the
+    full-width ``KernelEngine.run`` and the oracle on every published
+    deployment; ``transfer_words`` counts the words moved.
 """
 import os
 import subprocess
@@ -29,6 +34,7 @@ from deployments import DEPLOYMENTS, FABRICS
 from repro import ual
 from repro.core.dfg import interpret
 from repro.core.simulator import simulate_reference
+from repro.launch.mesh import forced_device_env
 from repro.ual.engine import CompiledKernelCache, bucket_ladder
 
 N_ITERS = 6
@@ -541,3 +547,195 @@ def test_compile_cache_placement(tmp_path, env_dir):
             else str(repo / "artifacts" / "jax_cache"))
     assert out[0] == out[1] == want
     assert float(out[2]) < 1.0
+
+
+# ---------------------------------------------------------------------------
+# packed blocks: only the declared arrays move between host and device
+# ---------------------------------------------------------------------------
+
+def test_live_runs_are_the_declared_arrays():
+    """``Program.live``: the arrays' words as ascending runs, adjacent
+    arrays merged — one run a bank at the default layout, one run where
+    the banks fill up, and the whole scratchpad where the arrays do."""
+    from repro.core.dfg import DFGBuilder
+    gemm = ual.Program.from_kernel("gemm")
+    assert gemm.live == ((0, 64), (2048, 64), (4096, 1))
+    assert ual.Program.from_kernel("gemm", bank_words=64).live == ((0, 129),)
+    b = DFGBuilder("full")
+    for name in "ABCD":
+        b.array(name, 16, output=name == "D")
+    i = b.counter()
+    b.store("D", i, b.load("A", i))
+    full = ual.Program.from_builder(b, 4, n_banks=4, bank_words=16)
+    assert full.live == ((0, 64),) and full.layout.total_words == 64
+
+
+def test_pack_batch_is_the_live_columns_of_flatten_batch(compiled):
+    """The packed block is the full-width image's live columns, with
+    missing arrays zero and short arrays zero-padded; unpacking it gives
+    the same dicts as unflattening the image."""
+    program, _ = compiled
+    rng = np.random.default_rng(19)
+    full = program.random_inputs(rng)
+    name = program.inputs[0]
+    short = dict(full, **{name: full[name][:3]})
+    missing = {k: v for k, v in full.items() if k != name}
+    mems = [full, short, missing, {}]
+    flats = program.flatten_batch(mems)
+    block = program.pack_batch(mems)
+    cols = np.concatenate([np.arange(lo, lo + n) for lo, n in program.live])
+    assert block.shape == (4, len(cols)) and block.dtype == np.int32
+    np.testing.assert_array_equal(block, flats[:, cols])
+    np.testing.assert_array_equal(program.pack_flats(flats), block)
+    for got, want in zip(program.unpack_batch(block),
+                         program.unflatten_batch(flats)):
+        assert set(got) == set(want) == set(program.arrays)
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k])
+    with pytest.raises(KeyError, match="unknown array"):
+        program.pack_batch([{"nope": np.zeros(4, np.int32)}])
+
+
+def _same_arrays(program, got, want):
+    for name in program.arrays:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+@pytest.mark.parametrize("kernel_name,fabric", DEPLOYMENTS)
+def test_packed_path_matches_full_width_every_deployment(kernel_name,
+                                                         fabric):
+    """Every packed entry of the pallas backend — ``execute_batch`` with
+    and without ``flats=``, and ``execute_stream`` — gives every declared
+    array bit-exact against the full-width ``KernelEngine.run`` of the
+    same images and the DFG interpreter, at the default scratchpad
+    (M = 8192): three images, padded to a bucket of 8, the stream's last
+    chunk ragged."""
+    program = ual.Program.from_kernel(kernel_name)
+    exe = ual.compile(program, ual.Target.from_name(
+        fabric, backend="pallas", **FABRICS[fabric]))
+    assert exe.success
+    n = program.n_iters
+    mems = _mems(program, 3, seed=23)
+    flats = program.flatten_batch(mems)
+    cache = CompiledKernelCache(buckets=(8,))
+    be = ual.backends.PallasBackend(engine=cache)
+    eng = cache.engine_for(exe.lowered)
+    full, _ = eng.run(flats, n)
+    assert full.shape == (3, 8192)
+    batch, _ = be.execute_batch(program, None, mems, n, lowered=exe.lowered)
+    given, _ = be.execute_batch(program, None, mems, n, lowered=exe.lowered,
+                                flats=flats)
+    gen = be.execute_stream(program, None, mems, n, chunk=2,
+                            lowered=exe.lowered)
+    stream = [d for outs, _ in gen for d in outs]
+    assert len(stream) == 3
+    for m, f, outs in zip(mems, program.unflatten_batch(full),
+                          zip(batch, given, stream)):
+        want = interpret(program.dfg, m, n)
+        _same_arrays(program, f, want)
+        for got in outs:
+            _same_arrays(program, got, want)
+    # one full-width and one packed shape, and the packed path moved
+    # only the live words: 2 x L x 8 rows for each of its four blocks
+    L = sum(ln for _, ln in program.live)
+    assert eng.traces == 2
+    assert eng.transfer_words == 2 * 8192 * 8 + 4 * 2 * L * 8
+
+
+def test_omitted_output_starts_at_zero():
+    """An output array that the caller leaves out starts at zero on the
+    packed path, and one it passes starts at the passed words: with two
+    iterations the fft writes two words of each 16-word output, and the
+    other 14 come back as they went in."""
+    program = ual.Program.from_kernel("fft", bank_words=64)
+    exe = ual.compile(program, ual.Target.from_name(
+        "hycube", rows=4, cols=4, backend="pallas"))
+    assert exe.success
+    rng = np.random.default_rng(29)
+    given = program.random_inputs(rng)
+    given.update({o: rng.integers(1, 50, program.arrays[o]).astype(np.int32)
+                  for o in program.outputs})
+    omitted = {k: v for k, v in program.random_inputs(rng).items()
+               if k not in program.outputs}
+    mems = [given, omitted]
+    cache = CompiledKernelCache(buckets=(8,))
+    out = ual.backends.PallasBackend(engine=cache).execute_batch(
+        program, None, mems, 2, lowered=exe.lowered)[0]
+    full = program.unflatten_batch(cache.run(
+        exe.lowered, program.flatten_batch(mems), 2)[0])
+    for m, got, f in zip(mems, out, full):
+        want = interpret(program.dfg, m, 2)
+        _same_arrays(program, got, want)
+        _same_arrays(program, f, want)
+    for o in program.outputs:
+        np.testing.assert_array_equal(out[0][o][2:], given[o][2:])
+        np.testing.assert_array_equal(out[1][o][2:], 0)
+
+
+def test_engine_counts_transfer_words(compiled):
+    """``transfer_words``: the int32 words each block moves up and back,
+    padding rows included — M a row on the full-width entry, L on the
+    packed one; ``run`` and ``run_stream`` both count, and the cache
+    sums its engines."""
+    program, exe = compiled
+    M = program.layout.total_words
+    L = sum(n for _, n in program.live)
+    assert L < M
+    cache = CompiledKernelCache()
+    eng = cache.engine_for(exe.lowered)
+    eng.run(np.zeros((5, M), np.int32), N_ITERS)                # 8 rows
+    assert eng.stats()["transfer_words"] == 2 * M * 8
+    packed = dict(live=program.live, M=M)
+    list(eng.run_stream(np.zeros((20, L), np.int32), N_ITERS, chunk=8,
+                        **packed))                              # 3 x 8 rows
+    eng.run(np.zeros((1, L), np.int32), N_ITERS, **packed)       # 1 row
+    want = 2 * M * 8 + 2 * L * (24 + 1)
+    assert eng.stats()["transfer_words"] == want
+    assert cache.stats()["transfer_words"] == want
+    with pytest.raises(ValueError, match="do not fit"):
+        eng.run(np.zeros((1, L + 1), np.int32), N_ITERS, **packed)
+
+
+def test_sharded_packed_parity_under_forced_two_devices():
+    """``pallas_sharded`` takes packed blocks too: in a fresh process
+    with two forced host devices, a batch of five (ragged against the
+    devices and the ladder) through ``execute_batch`` and
+    ``execute_stream`` matches the full-width sharded run and the
+    oracle bit for bit, moving L words an image."""
+    repo = Path(__file__).resolve().parents[1]
+    code = (
+        "import numpy as np\n"
+        "import jax\n"
+        "from repro import ual\n"
+        "from repro.core.dfg import interpret\n"
+        "from repro.ual.engine import CompiledKernelCache\n"
+        "assert len(jax.devices()) == 2\n"
+        "program = ual.Program.from_kernel('gemm')\n"
+        "exe = ual.compile(program, ual.Target.from_name(\n"
+        "    'hycube', rows=4, cols=4, backend='pallas'))\n"
+        "rng = np.random.default_rng(0)\n"
+        "mems = [program.random_inputs(rng) for _ in range(5)]\n"
+        "cache = CompiledKernelCache(buckets=(1, 8))\n"
+        "be = ual.backends.PallasBackend(engine=cache, sharded=True)\n"
+        "n = program.n_iters\n"
+        "batch, info = be.execute_batch(program, None, mems, n,\n"
+        "                               lowered=exe.lowered)\n"
+        "stream = [d for outs, _ in be.execute_stream(\n"
+        "    program, None, mems, n, chunk=16, lowered=exe.lowered)\n"
+        "    for d in outs]\n"
+        "eng = cache.sharded_engine_for(exe.lowered)\n"
+        "words = eng.transfer_words\n"
+        "full = program.unflatten_batch(cache.sharded_run(\n"
+        "    exe.lowered, program.flatten_batch(mems), n)[0])\n"
+        "ok = all(np.array_equal(got[k], interpret(program.dfg, m, n)[k])\n"
+        "         for outs in (batch, stream, full)\n"
+        "         for m, got in zip(mems, outs) for k in program.arrays)\n"
+        "print('DEVICES', info['n_devices'], 'PARITY', ok, 'WORDS', words)\n"
+    )
+    env = forced_device_env(2)
+    env["PYTHONPATH"] = str(repo / "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, cwd=str(repo), timeout=560)
+    assert out.returncode == 0, out.stderr[-2000:]
+    # five images: ceil(5 / 2) = 3 a device, bucket 8, 16 rows a block
+    assert f"DEVICES 2 PARITY True WORDS {2 * 2 * 129 * 16}" in out.stdout

@@ -18,7 +18,11 @@ The contract under test:
     admission verdicts (all-or-nothing ``queue-full``, ``shutdown``),
   * the satellite fast paths: a batch that IS a bucket size skips the
     pad/copy staging entirely, and ``validate``'s multi-backend sweep
-    flattens its test vectors exactly once.
+    flattens its test vectors exactly once,
+  * packed blocks: the stream carries only the declared arrays' words,
+    bit-exact against the full-width run with a ragged last chunk; a
+    warmed backend traces one packed shape per bucket and nothing after;
+    each benchmark deployment moves 2 x L words an image.
 """
 import numpy as np
 import pytest
@@ -373,3 +377,90 @@ def test_validate_flattens_once_per_multi_backend_sweep(compiled,
     report = exe.validate(seed=5, backends=("sim", "pallas"), n_vectors=4)
     assert report.passed
     assert calls == [4]          # one flatten feeds both backend sweeps
+
+
+# ---------------------------------------------------------------------------
+# packed blocks: the stream moves only the declared arrays
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,chunk", [(37, 8), (70, 32)])
+def test_packed_stream_matches_full_width_run(compiled, B, chunk):
+    """The packed stream, its last chunk ragged and padded up a bucket,
+    gives every declared array bit-exact against the full-width
+    ``KernelEngine.run`` of the same images."""
+    program, exe = compiled
+    mems = _mems(program, B, seed=B + 1)
+    cache = CompiledKernelCache()
+    full, _ = cache.run(exe.lowered, program.flatten_batch(mems), N_ITERS)
+    gen = ual.backends.PallasBackend(engine=cache).execute_stream(
+        program, None, mems, N_ITERS, chunk=chunk, lowered=exe.lowered)
+    chunks, summary = _drain(gen)
+    assert len(chunks[-1][0]) == B % chunk
+    assert summary["padded"] > 0
+    got = [d for outs, _ in chunks for d in outs]
+    for g, f in zip(got, program.unflatten_batch(full)):
+        for name in program.arrays:
+            np.testing.assert_array_equal(g[name], f[name])
+
+
+def test_warm_backend_runs_stream_and_batch_with_zero_new_traces(compiled):
+    """``PallasBackend.warmup`` traces one packed shape per bucket and no
+    full-width one; after it, batch and stream traffic of every size
+    (a bucket, ragged, beyond the top bucket) adds no trace."""
+    program, exe = compiled
+    cache = CompiledKernelCache(buckets=(1, 8))
+    prev = ual.set_default_engine(cache)
+    try:
+        stats = exe.warmup()
+        L = sum(n for _, n in program.live)
+        M = program.layout.total_words
+        assert stats["traces"] == 2
+        assert stats["warm_shapes"] == [(M, 1, program.live),
+                                        (M, 8, program.live)]
+        for B in (1, 5, 8, 12):
+            exe.run_batch(_mems(program, B, seed=B), n_iters=N_ITERS)
+            assert exe.last_info["traced"] == 0
+        for B, chunk in ((11, 8), (3, 1), (16, None)):
+            outs = list(exe.run_stream(_mems(program, B, seed=B),
+                                       n_iters=N_ITERS, chunk=chunk))
+            assert sum(len(o) for o in outs) == B
+            assert exe.last_info["traced"] == 0
+        eng = cache.engine_for(exe.lowered)
+        assert eng.traces == 2
+        assert eng.transfer_words % (2 * L) == 0
+    finally:
+        ual.set_default_engine(prev)
+
+
+#: words an image moves, up plus back: the live words of each cell's
+#: configuration (``chipbench/configs``), against 2 x 8192 full-width
+CELL_WORDS = {"hycube4x4-gemm": 258, "pace8x8-fft": 320,
+              "pace8x8-fft1024": 6144}
+
+
+@pytest.mark.parametrize("config", sorted(CELL_WORDS))
+def test_transfer_words_per_image_of_each_cell(config):
+    """The benchmark's three deployments, as their configuration files
+    state them, move 2 x L words an image on the packed path (a full
+    8-image block, so no padding row) and 2 x M on the full-width one."""
+    import json
+    from pathlib import Path
+    repo = Path(__file__).resolve().parents[1]
+    cfg = json.loads((repo / "chipbench" / "configs" / f"{config}.json")
+                     .read_text())
+    program = ual.Program.from_kernel(
+        cfg["kernel"], n_banks=cfg["n_banks"], bank_words=cfg["bank_words"])
+    exe = ual.compile(program, ual.Target.from_name(
+        cfg["fabric"], backend=cfg["backend"], **cfg["fabric_args"]))
+    assert exe.success and program.layout.total_words == 8192
+    mems = _mems(program, 8, seed=37)
+    cache = CompiledKernelCache(buckets=(8,))
+    eng = cache.engine_for(exe.lowered)
+    packed = ual.backends.PallasBackend(engine=cache).execute_batch(
+        program, None, mems, 1, lowered=exe.lowered)[0]
+    assert eng.transfer_words / 8 == CELL_WORDS[config]
+    full, _ = eng.run(program.flatten_batch(mems), 1)
+    assert (eng.transfer_words / 8 - CELL_WORDS[config]) == 2 * 8192
+    for got, want in zip(packed, program.unflatten_batch(full)):
+        for name in program.arrays:
+            np.testing.assert_array_equal(got[name], want[name])
