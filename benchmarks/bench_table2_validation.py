@@ -18,8 +18,9 @@ from benchmarks.common import fmt_table, save
 
 N_VECTORS = 4
 #: the paper's loop bodies at the repo's trip count; the MachSuite-size
-#: deployments (``fft1024``: 5,120 iterations) belong to the chip benchmark
-TABLE2_KERNELS = tuple(k for k in KERNELS if k != "fft1024")
+#: deployments (``fft1024``: 5,120 iterations, ``gemm64``: 65,536) belong
+#: to the chip benchmark
+TABLE2_KERNELS = tuple(k for k in KERNELS if k not in ("fft1024", "gemm64"))
 
 
 def run(seed: int = 0, verbose: bool = True) -> dict:

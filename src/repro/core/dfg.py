@@ -205,15 +205,18 @@ class DFGBuilder:
             self._outputs.append(name)
         return name
 
-    def load(self, array: str, idx) -> Ref:
-        """LOAD: operands [idx?]; const holds the (base+)fixed offset."""
+    def load(self, array: str, idx, offset: int = 0) -> Ref:
+        """LOAD of ``array[idx + offset]``: operands [idx?]; const holds
+        the fixed offset (``idx`` itself when it is a constant), to which
+        the layout adds the array's base."""
         assert array in self._arrays, f"undeclared array {array}"
         if isinstance(idx, (int, np.integer)):
-            return self.op("LOAD", const=int(idx), array=array)
-        return self.op("LOAD", idx, array=array)
+            return self.op("LOAD", const=int(idx) + int(offset), array=array)
+        return self.op("LOAD", idx, const=int(offset) or None, array=array)
 
-    def store(self, array: str, idx, value) -> Ref:
-        """STORE: operands [idx?, value]; const holds the fixed offset."""
+    def store(self, array: str, idx, value, offset: int = 0) -> Ref:
+        """STORE to ``array[idx + offset]``: operands [idx?, value]; const
+        holds the fixed offset, as ``load``'s."""
         assert array in self._arrays, f"undeclared array {array}"
         if array not in self._outputs:
             self._outputs.append(array)
@@ -222,12 +225,13 @@ class DFGBuilder:
         if isinstance(idx, (int, np.integer)):
             nid = len(self._nodes)
             self._nodes.append(Node(nid, "STORE", [Operand(value.id)],
-                                    const=int(idx), array=array))
+                                    const=int(idx) + int(offset),
+                                    array=array))
             return Ref(nid)
         nid = len(self._nodes)
         self._nodes.append(Node(nid, "STORE",
                                 [Operand(idx.id), Operand(value.id)],
-                                array=array))
+                                const=int(offset) or None, array=array))
         return Ref(nid)
 
     # -- loop-carried state ---------------------------------------------------

@@ -8,9 +8,10 @@ Morpher's automated test-vector flow.
 
 DFG sizes are chosen to be representative of the paper's kernels on a 4x4
 fabric (ResMII in the 2-4 range, so routing pressure — not raw FU count —
-decides II, which is what Table III measures).  ``fft_strided`` is the
-exception: MachSuite's whole strided FFT at the suite's own size
-(``fft1024``, 5,120 iterations of one butterfly each).
+decides II, which is what Table III measures).  ``fft_strided`` and
+``gemm_ncubed`` are the exceptions: MachSuite's whole strided FFT and
+matrix product at the suite's own sizes (``fft1024``, 5,120 iterations
+of one butterfly each; ``gemm64``, 65,536 iterations of four products).
 """
 from __future__ import annotations
 
@@ -134,6 +135,48 @@ def fft_strided(n: int = 1024) -> KernelEntry:
         return {"xr": _rand(r, n, -32768, 32768),
                 "xi": _rand(r, n, -32768, 32768), **twiddles}
     return b.build(), mk, half * (lg + 1)
+
+
+def gemm_ncubed(n: int = 64) -> KernelEntry:
+    """MachSuite ``gemm/ncubed``: ``prod = m1 @ m2`` over ``n`` x ``n``
+    row-major matrices, the whole ``i, j, k`` nest as one loop.
+
+    ``k`` is unrolled by 4, as in ``gemm``, so the loop runs ``n**3 / 4``
+    iterations ``t = (i*n + j) * n/4 + k4``: ``t >> (log2 n - 2)`` is
+    ``i*n + j``, its low ``log2 n`` bits are ``j``, and ``t & (n/4 - 1)``
+    is ``k4``.  The four products of ``k = 4*k4 .. 4*k4 + 3`` add to a
+    running sum that a ``SELECT`` restarts at 0 where ``k4 == 0``;
+    ``prod[i*n + j]`` is stored every iteration, so the last ``k4`` of
+    each ``(i, j)`` leaves the whole sum.  The unrolled loads share one
+    index and fold ``u`` (``m1``) or ``u*n`` (``m2``) into their offset.
+    """
+    if n < 8 or n & (n - 1):
+        raise ValueError(f"gemm_ncubed: n must be a power of two >= 8, "
+                         f"got {n}")
+    lg = n.bit_length() - 1                      # log2 n
+    q = n // 4                                   # k4 runs over [0, q)
+    b = DFGBuilder(f"gemm{n}")
+    b.array("m1", n * n)
+    b.array("m2", n * n)
+    b.array("prod", n * n, output=True)
+    t = b.counter()
+    k4 = b.op("AND", t, q - 1)
+    ij = b.op("SHR", t, lg - 2)                  # i*n + j
+    j = b.op("AND", ij, n - 1)
+    row = b.op("OR", b.op("SUB", ij, j), b.op("SHL", k4, 2))   # i*n + 4*k4
+    col = b.op("OR", b.op("SHL", k4, lg + 2), j)               # 4*k4*n + j
+    parts = [b.op("MUL", b.load("m1", row, offset=u),
+                  b.load("m2", col, offset=u * n)) for u in range(4)]
+    s = b.op("ADD", b.op("ADD", parts[0], parts[1]),
+             b.op("ADD", parts[2], parts[3]))
+    acc = b.recur(0)
+    acc2 = b.op("ADD", b.op("SELECT", b.op("CMPNE", k4, 0), acc, 0), s)
+    b.bind(acc, acc2)
+    b.store("prod", ij, acc2)
+
+    def mk(r):
+        return {"m1": _rand(r, n * n), "m2": _rand(r, n * n)}
+    return b.build(), mk, n * n * q
 
 
 def adpcm() -> KernelEntry:
@@ -327,5 +370,6 @@ KERNELS: Dict[str, Callable[[], KernelEntry]] = {
     "dct": dct,
     "nw": nw,
     "gemm": gemm,
+    "gemm64": lambda: gemm_ncubed(64),
     "jax_poly": jax_poly,
 }

@@ -74,6 +74,55 @@ def test_recurrence_init_semantics():
     np.testing.assert_array_equal(out["out"], [101, 102, 103, 104])
 
 
+def _offset_copy():
+    """``out[i + 2] = 3 * x[i + 1]`` and ``out[0] = x[5]``, the offsets
+    given to ``load``/``store`` and folded into the nodes' consts."""
+    b = DFGBuilder("offset_copy")
+    b.array("x", 16)
+    b.array("out", 16, output=True)
+    i = b.counter()
+    b.store("out", i, b.op("MUL", b.load("x", i, offset=1), 3), offset=2)
+    b.store("out", 0, b.load("x", 3, offset=2))
+    return b
+
+
+def test_offset_load_and_store_fold_into_const():
+    dfg = _offset_copy().build()
+    mem = [(n.op, len(n.operands), n.const) for n in dfg.nodes
+           if n.op in ("LOAD", "STORE")]
+    assert mem == [("LOAD", 1, 1), ("STORE", 2, 2), ("LOAD", 0, 5),
+                   ("STORE", 1, 0)]
+    xs = np.arange(100, 116, dtype=np.int32)
+    out = interpret(dfg, {"x": xs}, 8)
+    want = np.zeros(16, np.int32)
+    want[2:10] = 3 * xs[1:9]
+    want[0] = xs[5]
+    np.testing.assert_array_equal(out["out"], want)
+    # no offset leaves the node as it was: an index and no const
+    b = DFGBuilder("plain")
+    b.array("x", 4)
+    b.array("y", 4, output=True)
+    i = b.counter()
+    b.store("y", i, b.load("x", i))
+    assert [n.const for n in b.build().nodes if n.op in ("LOAD", "STORE")] \
+        == [None, None]
+
+
+def test_offset_load_and_store_on_sim():
+    """The folded offsets reach the cycle-accurate simulator through the
+    layout's base, bit-exact against the interpreter."""
+    from repro import ual
+    program = ual.Program.from_builder(_offset_copy(), 8)
+    exe = ual.compile(program, ual.Target.from_name(
+        "hycube", backend="sim", rows=4, cols=4))
+    assert exe.success
+    xs = np.arange(-8, 8, dtype=np.int32) * 7
+    (got,) = exe.run_batch([{"x": xs}], 8, backend="sim")
+    want = interpret(program.dfg, {"x": xs}, 8)
+    np.testing.assert_array_equal(got["out"], want["out"])
+    assert got["out"][2] == 3 * xs[1] and got["out"][0] == xs[5]
+
+
 def test_layout_round_robin_and_flat_roundtrip():
     dfg, mk, _ = KERNELS["fft"]()
     lay = plan_layout(dfg, n_banks=4, bank_words=512)

@@ -30,7 +30,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from deployments import DEPLOYMENTS, FABRICS
+from deployments import DEPLOYMENTS, FABRICS, program as deployed
 from repro import ual
 from repro.core.dfg import interpret
 from repro.core.simulator import simulate_reference
@@ -299,7 +299,7 @@ def test_copy_lists_match_dense_gather(kernel_name, fabric):
     from repro.core.lowering import (K_O, K_R, K_RESULT, KC_HEAD, KC_MOVES,
                                      KC_OPS, KC_RES, KC_WIDTH, KV_LIVE,
                                      KV_Q0, kernel_tables, state_copy_counts)
-    exe = ual.compile(ual.Program.from_kernel(kernel_name),
+    exe = ual.compile(deployed(kernel_name),
                       ual.Target.from_name(fabric, backend="pallas",
                                            **FABRICS[fabric]))
     assert exe.success
@@ -610,7 +610,7 @@ def test_packed_path_matches_full_width_every_deployment(kernel_name,
     same images and the DFG interpreter, at the default scratchpad
     (M = 8192): three images, padded to a bucket of 8, the stream's last
     chunk ragged."""
-    program = ual.Program.from_kernel(kernel_name)
+    program = deployed(kernel_name)
     exe = ual.compile(program, ual.Target.from_name(
         fabric, backend="pallas", **FABRICS[fabric]))
     assert exe.success

@@ -7,7 +7,7 @@ scratchpad passes that the compile pipeline lowers.
 import numpy as np
 import pytest
 
-from deployments import DEPLOYMENTS, FABRICS
+from deployments import DEPLOYMENTS, FABRICS, program as deployed
 from repro import ual
 from repro.kernels.cgra_exec.ops import cgra_exec_op
 from repro.kernels.cgra_exec.ref import cgra_exec_ref
@@ -16,7 +16,7 @@ from repro.kernels.cgra_exec.ref import cgra_exec_ref
 def _compiled(kernel_name, fabric):
     """Compile via the UAL so identical pairs are mapped once per session
     (the conftest installs a shared mapping cache)."""
-    exe = ual.compile(ual.Program.from_kernel(kernel_name),
+    exe = ual.compile(deployed(kernel_name),
                       ual.Target.from_name(fabric, **FABRICS[fabric]))
     assert exe.success, f"{kernel_name} failed to map on {fabric}"
     return exe

@@ -27,7 +27,7 @@ The contract under test:
 import numpy as np
 import pytest
 
-from deployments import DEPLOYMENTS, FABRICS
+from deployments import DEPLOYMENTS, FABRICS, program as deployed
 from repro import ual
 from repro.core.dfg import interpret
 from repro.ual.engine import CompiledKernelCache
@@ -91,7 +91,7 @@ def test_run_stream_matches_oracle_every_deployment(kernel_name, fabric):
     every published deployment at the default scratchpad: three seeded
     images at chunk=2 through the pallas engine, every output array
     bit-exact against the DFG interpreter."""
-    program = ual.Program.from_kernel(kernel_name)
+    program = deployed(kernel_name)
     exe = ual.compile(program, ual.Target.from_name(
         fabric, backend="pallas", **FABRICS[fabric]))
     assert exe.success

@@ -3,10 +3,11 @@
 The TPU compiler is installed even where no chip is attached, and it
 compiles for a *described* chip.  These tests compile the kernel of the
 main path at the real width — the default scratchpad (M = 8192) and the
-engine's bucket sizes 8 and 128, on the published fabrics, and the
-benchmark's two FFTs on PACE 8x8 at 128 lanes — for one chip
-of a described ``v5e:2x2``, and check that the Mosaic kernel is in the
-compiled program.  Nothing
+engine's bucket sizes 8 and 128, on the published fabrics, the
+benchmark's two FFTs on PACE 8x8 at 128 lanes, and MachSuite's whole
+64x64 gemm on HyCUBE 4x4 at its M = 16,384 — for one chip of a described
+``v5e:2x2``, and check that the Mosaic kernel is in the compiled
+program.  Nothing
 runs, so they say nothing about results or times; the interpret-mode
 tests (``test_engine.py`` and friends) hold the semantics.
 
@@ -21,8 +22,6 @@ import pytest
 
 from deployments import FABRICS
 from repro import ual
-
-M = 8192
 
 
 @pytest.fixture(scope="module")
@@ -54,16 +53,19 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-#: gemm on every fabric at both widths, and the benchmark's two FFTs on
-#: PACE (a butterfly stage, and the whole 1,024-point transform)
-CASES = [pytest.param(fabric, bB, "gemm", id=f"{fabric}-{bB}")
+#: (fabric, lanes, kernel, scratchpad words M in 4 banks): gemm on every
+#: fabric at both widths, the benchmark's two FFTs on PACE (a butterfly
+#: stage, and the whole 1,024-point transform), and the whole 64x64 gemm
+#: on HyCUBE, whose three 4,096-word arrays need M = 16,384
+CASES = [pytest.param(fabric, bB, "gemm", 8192, id=f"{fabric}-{bB}")
          for fabric in sorted(FABRICS) for bB in (8, 128)] + [
-    pytest.param("pace", 128, k, id=f"pace-128-{k}")
-    for k in ("fft", "fft1024")]
+    pytest.param("pace", 128, k, 8192, id=f"pace-128-{k}")
+    for k in ("fft", "fft1024")] + [
+    pytest.param("hycube", 128, "gemm64", 16384, id="hycube-128-gemm64")]
 
 
-@pytest.mark.parametrize("fabric,bB,kernel_name", CASES)
-def test_cgra_exec_compiles_for_v5e(fabric, bB, kernel_name, one_chip,
+@pytest.mark.parametrize("fabric,bB,kernel_name,M", CASES)
+def test_cgra_exec_compiles_for_v5e(fabric, bB, kernel_name, M, one_chip,
                                     no_persistent_cache, monkeypatch):
     import jax
     import jax.numpy as jnp
@@ -76,7 +78,7 @@ def test_cgra_exec_compiles_for_v5e(fabric, bB, kernel_name, one_chip,
     monkeypatch.setattr(kernel, "interpret_mode", lambda: False)
     monkeypatch.setattr(kernel, "use_compile_cache", lambda: None)
 
-    program = ual.Program.from_kernel(kernel_name)
+    program = ual.Program.from_kernel(kernel_name, bank_words=M // 4)
     assert program.layout.total_words == M
     exe = ual.compile(program, ual.Target.from_name(
         fabric, backend="pallas", **FABRICS[fabric]))

@@ -12,7 +12,7 @@ from dataclasses import replace
 import numpy as np
 import pytest
 
-from deployments import DEPLOYMENTS, FABRICS
+from deployments import DEPLOYMENTS, FABRICS, program as deployed
 from repro import ual
 from repro.analysis.verifier import (CODES, CheckReport, Diagnostic,
                                      VerifyError, raise_if_errors, verify)
@@ -26,8 +26,7 @@ from repro.core.simulator import BatchedSimulator
 
 def _compiled(kernel, fab_name, kwargs):
     target = ual.Target.from_name(fab_name, **kwargs)
-    program = ual.Program.from_kernel(
-        kernel, n_banks=max(1, target.fabric.n_mem_ports))
+    program = deployed(kernel, n_banks=max(1, target.fabric.n_mem_ports))
     exe = ual.compile(program, target)
     assert exe.success, f"{kernel} must map onto {fab_name}"
     return program, target, exe
